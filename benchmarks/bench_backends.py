@@ -55,7 +55,7 @@ def _iters_for(events: int, nprocs: int) -> int:
     return max(16, int(round(events / per_iter)))
 
 
-def _tolerant_equal(op, a, b) -> bool:
+def tolerant_equal(op, a, b) -> bool:
     """pallas vs numpy: f32 rounding on sums, exact everywhere else.
 
     f32 accumulation error scales with the *accumulated magnitude*, not a
@@ -115,7 +115,7 @@ def bench(events: int = DEFAULT_EVENTS) -> dict:
                 res = eager.query().run(op, cache=False, backend=b,
                                         **kwargs)
                 rec[f"{b}_eager_s"] = round(time.perf_counter() - t0, 3)
-                rec[f"{b}_matches_numpy"] = _tolerant_equal(op, ref, res)
+                rec[f"{b}_matches_numpy"] = tolerant_equal(op, ref, res)
                 t0 = time.perf_counter()
                 sres = stream.query().run(op, cache=False, backend=b,
                                           **kwargs)

@@ -145,6 +145,7 @@ def _ops_trace(events: int, tmp: str):
 def op_bandwidth(events: int = DEFAULT_OPS_EVENTS) -> Dict:
     """Achieved vs. peak bytes/s for every registered backend of every
     kernel-backed op at ``events`` scale."""
+    import jax
     import numpy as np
     from repro.core import registry
     from repro.core.constants import ENTER, ET, MPI_SEND, NAME
@@ -177,8 +178,8 @@ def op_bandwidth(events: int = DEFAULT_OPS_EVENTS) -> Dict:
         n_events = len(ev)
     return {"mode": "op_bandwidth", "events": n_events,
             "nprocs": OPS_NPROCS, "peak_gib_s": round(peak / 2**30, 2),
-            "interpret_mode": os.environ.get("REPRO_PALLAS_COMPILE",
-                                             "0") != "1",
+            # the kernels compile on a TPU and are interpreted elsewhere
+            "interpret_mode": jax.default_backend() != "tpu",
             "rows": rows, "ok": True}
 
 

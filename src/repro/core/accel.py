@@ -33,16 +33,30 @@ __all__ = ["canonical_order", "alpha_positions", "block_size", "seg_sum",
            "pair_sum", "hist_counts"]
 
 
-def block_size(n: int) -> int:
-    """Deterministic event-block size for the record kernels: 256 for
-    small inputs, doubled until the sequential grid stays under ~512 steps
-    (interpret mode walks the grid at Python speed, so step count — not
-    record count — dominates CPU wall time; a real TPU bounds ``be`` by
-    VMEM instead).  A pure function of N: every execution path holding the
-    same record multiset picks the same partitioning, which keeps f32
+# One-hot tile bound, in f32 elements: a block builds [rows, BE] one-hot and
+# overlap tiles, and Mosaic both holds them in VMEM (16 MiB scoped on a
+# v5e) and unrolls its vector code over them, so compile time and code
+# size grow with rows·BE.  2¹⁹ elements is a 2 MiB f32 tile.
+_TILE_ELEMS = 1 << 19
+
+
+def block_size(n: int, rows: int) -> int:
+    """Deterministic record-block size (lanes) for the record kernels.
+
+    ``rows`` is the number of one-hot rows a block builds per record (the
+    output widths, e.g. ``n_seg`` or ``n_a + n_b``).  The block is 256
+    lanes for small inputs, doubled until the sequential grid stays under
+    ~512 steps, and capped so that ``rows × BE`` (rows padded to the
+    8-sublane tile) stays within one 2 MiB f32 tile — never below the
+    128-lane width, never above 32768.  A pure function of the record count
+    and the output widths: every execution path holding the same record
+    multiset for the same op picks the same partitioning, which keeps f32
     block sums — and therefore result digests — path-identical."""
-    be = 256
-    while n > be * 512 and be < 65536:
+    rows8 = -(-max(int(rows), 1) // 8) * 8
+    cap = max(_TILE_ELEMS // rows8, 128)
+    cap = min(1 << (cap.bit_length() - 1), 32768)
+    be = min(256, cap)
+    while n > be * 512 and be < cap:
         be *= 2
     return be
 
@@ -86,10 +100,11 @@ def seg_sum(code: np.ndarray, values: np.ndarray, n_seg: int) -> np.ndarray:
     if n_seg <= 0 or values.shape[1] == 0:
         out = np.zeros((max(n_seg, 0), values.shape[1]))
         return out[:, 0] if squeeze else out
+    # lane-major: the kernel takes one value row per metric
     out = np.asarray(segment_sum_matrix(
-        jnp.asarray(np.asarray(code, np.int64)),
-        jnp.asarray(values, jnp.float32), n_seg=int(n_seg),
-        be=block_size(len(values))), np.float64)
+        jnp.asarray(np.asarray(code, np.int32)),
+        jnp.asarray(np.ascontiguousarray(values.T, np.float32)),
+        n_seg=int(n_seg), be=block_size(len(values), n_seg)), np.float64)
     return out[:, 0] if squeeze else out
 
 
@@ -103,11 +118,11 @@ def pair_sum(a: np.ndarray, b: np.ndarray, w: np.ndarray, n_a: int,
 
     from ..kernels.ops import pair_sum_matrix
     return np.asarray(pair_sum_matrix(
-        jnp.asarray(np.asarray(a, np.int64)),
-        jnp.asarray(np.asarray(b, np.int64)),
-        jnp.asarray(np.asarray(w, np.float64), jnp.float32),
-        n_a=int(n_a), n_b=int(n_b), be=block_size(len(np.asarray(a)))),
-        np.float64)
+        jnp.asarray(np.asarray(a, np.int32)),
+        jnp.asarray(np.asarray(b, np.int32)),
+        jnp.asarray(np.asarray(w, np.float32)),
+        n_a=int(n_a), n_b=int(n_b),
+        be=block_size(len(np.asarray(a)), n_a + n_b)), np.float64)
 
 
 def hist_counts(idx: np.ndarray, n_bins: int) -> np.ndarray:
@@ -123,5 +138,5 @@ def hist_counts(idx: np.ndarray, n_bins: int) -> np.ndarray:
     coords = np.asarray(idx, np.float64) + 0.5
     out = np.asarray(histogram_counts(
         jnp.asarray(coords, jnp.float32), n_bins=int(n_bins),
-        be=block_size(len(coords))))
+        be=block_size(len(coords), n_bins)))
     return np.rint(out).astype(np.int64)

@@ -303,7 +303,7 @@ def _pallas_profile(starts, ends, rate, name_codes, edges, nf) -> np.ndarray:
     return np.asarray(time_profile_matrix(
         (starts - t0) / bw, (ends - t0) / bw, name_codes, rate * bw,
         n_funcs=nf, n_bins=num_bins, t0=0.0, t1=float(num_bins),
-        be=accel.block_size(len(starts)))).T
+        be=accel.block_size(len(starts), nf + num_bins))).T
 
 
 @register_time_profile_backend("numpy")

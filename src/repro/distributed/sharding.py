@@ -24,36 +24,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["ShardingRules", "DEFAULT_RULES", "rules_for", "logical_to_spec",
            "spec_tree", "batch_spec", "named_sharding_tree",
-           "activation_sharding", "constrain", "shard_map_compat"]
+           "activation_sharding", "constrain"]
 
-
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs,
-                     manual_axes: Optional[frozenset] = None):
-    """``jax.shard_map`` across the 0.4 → 0.8 API churn.
-
-    jax 0.4 spells it ``shard_map(..., check_rep=, auto=)`` (``auto`` = the
-    mesh axes that stay GSPMD-automatic); newer releases renamed the pair to
-    ``check_vma=`` / ``axis_names=`` (the axes that are *manual*).
-    ``manual_axes`` here always means the manual subset; replication
-    checking is disabled either way (the int8-wire collective is
-    deliberately non-replicated).
-    """
-    import inspect
-    try:
-        from jax import shard_map as _sm
-    except ImportError:  # jax < 0.6
-        from jax.experimental.shard_map import shard_map as _sm
-    params = inspect.signature(_sm).parameters
-    kw = {}
-    if "check_vma" in params:
-        kw["check_vma"] = False
-        if manual_axes is not None and "axis_names" in params:
-            kw["axis_names"] = set(manual_axes)
-    else:
-        kw["check_rep"] = False
-        if manual_axes is not None and "auto" in params:
-            kw["auto"] = frozenset(mesh.axis_names) - frozenset(manual_axes)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
 
 Axis = Union[None, str, Tuple[str, ...]]
 

@@ -25,10 +25,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention"]
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4/0.5; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 _NEG = -1e30
 
 
@@ -113,7 +109,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, prefix_len=0,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -1,14 +1,13 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On this CPU container the kernels run with ``interpret=True`` (Pallas
-executes the kernel body in Python for correctness); on a real TPU pass
-``interpret=False`` (or set ``REPRO_PALLAS_COMPILE=1``).
+The kernels compile for the chip when JAX's default backend is a TPU and
+run in Pallas interpret mode on any other backend.  The choice is made
+when a wrapper is first traced (:func:`_interpret`), never at import.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +22,10 @@ from .topk_gating import topk_gating as _topk
 __all__ = ["flash_attention_gqa", "time_profile_matrix", "router_topk",
            "segment_sum_matrix", "pair_sum_matrix", "histogram_counts"]
 
-_INTERPRET = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
+
+def _interpret() -> bool:
+    """Interpret mode everywhere but on a TPU, which always compiles."""
+    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "prefix_len",
@@ -41,7 +43,7 @@ def flash_attention_gqa(q, k, v, *, causal=True, window=None, prefix_len=0,
     kf = jnp.repeat(k.transpose(0, 2, 1, 3), G, axis=1).reshape(B * H, -1, D)
     vf = jnp.repeat(v.transpose(0, 2, 1, 3), G, axis=1).reshape(B * H, -1, D)
     out = _flash(qf, kf, vf, causal=causal, window=window,
-                 prefix_len=prefix_len, bq=bq, bk=bk, interpret=_INTERPRET)
+                 prefix_len=prefix_len, bq=bq, bk=bk, interpret=_interpret())
     return out.reshape(B, H, S, D).transpose(0, 2, 1, 3)
 
 
@@ -50,30 +52,30 @@ def flash_attention_gqa(q, k, v, *, causal=True, window=None, prefix_len=0,
 def time_profile_matrix(start, end, func, rate=None, *, n_funcs, n_bins,
                         t0, t1, be=256):
     return _time_bin(start, end, func, rate, n_funcs=n_funcs, n_bins=n_bins,
-                     t0=t0, t1=t1, be=be, interpret=_INTERPRET)
+                     t0=t0, t1=t1, be=be, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
 def router_topk(logits, k: int):
-    return _topk(logits, k, interpret=_INTERPRET)
+    return _topk(logits, k, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("n_seg", "be"))
 def segment_sum_matrix(code, values, *, n_seg, be=256):
-    """code [N] (<0 ignored), values [N, K] → [n_seg, K] f32 segment sums
+    """code [N] (<0 ignored), values [K, N] → [n_seg, K] f32 segment sums
     (repro.kernels.seg_sum) — flat_profile / per-rank busy-sum backend."""
-    return _seg_sum(code, values, n_seg=n_seg, be=be, interpret=_INTERPRET)
+    return _seg_sum(code, values, n_seg=n_seg, be=be, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("n_a", "n_b", "be"))
 def pair_sum_matrix(a, b, w, *, n_a, n_b, be=256):
     """a, b [N] (<0 ignored), w [N] → [n_a, n_b] f32 weighted 2-D
     scatter-add (repro.kernels.pair_sum) — comm_matrix backend."""
-    return _pair_sum(a, b, w, n_a=n_a, n_b=n_b, be=be, interpret=_INTERPRET)
+    return _pair_sum(a, b, w, n_a=n_a, n_b=n_b, be=be, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("n_bins", "be"))
 def histogram_counts(coords, *, n_bins, be=256):
     """coords [N] f32 bin coordinates (<0 ignored) → [n_bins] f32 counts
     (repro.kernels.hist_bin) — message_histogram backend."""
-    return _hist_bin(coords, n_bins=n_bins, be=be, interpret=_INTERPRET)
+    return _hist_bin(coords, n_bins=n_bins, be=be, interpret=_interpret())

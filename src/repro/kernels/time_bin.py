@@ -3,15 +3,16 @@
 The paper's hottest analysis loop (§IV-B): for every function call (start,
 end, func) and every time bin, accumulate the overlap length into a
 ``[functions × bins]`` matrix.  The TPU adaptation replaces the pandas
-groupby with a *one-hot matmul*: a block of BE events computes its
-``[BE, NB]`` overlap matrix in VREGs, then lifts it to ``[F, NB]`` on the
-MXU via ``onehot(func)ᵀ @ overlap`` — scatter-free accumulation, which is
-exactly how a TPU wants to build histograms.
+groupby with a *one-hot matmul*.  Events sit on lanes: start, end, func
+and rate are ``[1, N]``, blocked ``(1, BE)``.  A block computes its
+``[NB, BE]`` rate-weighted overlap matrix against bin edges built from an
+int32 iota, then lifts it to ``[F, NB]`` via ``onehot(func) · overlapᵀ``
+— one ``dot_general`` contracting the lane axis of both, at full f32
+precision.
 
-Grid is 1-D over event blocks (sequential), with the output block mapped to
-the same ``(F, NB)`` tile every step so the kernel accumulates in place.
-VMEM: BE·(3 vectors) + BE·NB + BE·F + F·NB  ≈ 1.3 MB at BE=256, NB=256,
-F=128.
+Grid is 1-D over event blocks (sequential), with the output block the
+whole ``(F, NB)`` array every step so the kernel accumulates in place.
+Padding events carry func ``-1`` and rate 0.
 """
 
 from __future__ import annotations
@@ -27,29 +28,22 @@ __all__ = ["time_bin"]
 
 def _kernel(start_ref, end_ref, func_ref, rate_ref, out_ref, *, n_funcs,
             n_bins, t0, bin_w):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    s = start_ref[...].astype(jnp.float32)              # [BE]
-    e = end_ref[...].astype(jnp.float32)
-    f = func_ref[...]                                   # [BE] int32 (<0 pad)
-    r = rate_ref[...].astype(jnp.float32)               # [BE] weight/second
-
-    be = s.shape[0]
-    edges_lo = t0 + bin_w * jax.lax.broadcasted_iota(
-        jnp.float32, (be, n_bins), 1)
-    ov = (jnp.minimum(e[:, None], edges_lo + bin_w)
-          - jnp.maximum(s[:, None], edges_lo))
-    ov = jnp.maximum(ov, 0.0)                            # [BE, NB]
-    ov = jnp.where((f >= 0)[:, None], ov * r[:, None], 0.0)
-
-    onehot = (jax.lax.broadcasted_iota(jnp.int32, (be, n_funcs), 1)
-              == jnp.maximum(f, 0)[:, None]).astype(jnp.float32)
+    f = func_ref[...]                                   # [1, BE] (<0 pad)
+    be = f.shape[1]
+    lo = t0 + bin_w * jax.lax.broadcasted_iota(
+        jnp.int32, (n_bins, be), 0).astype(jnp.float32)  # [NB, BE]
+    ov = jnp.maximum(jnp.minimum(end_ref[...], lo + bin_w)
+                     - jnp.maximum(start_ref[...], lo), 0.0)
+    ov = jnp.where(f >= 0, ov * rate_ref[...], 0.0)      # [NB, BE]
+    onehot = (jax.lax.broadcasted_iota(jnp.int32, (n_funcs, be), 0)
+              == f).astype(jnp.float32)                  # [F, BE]
     out_ref[...] += jax.lax.dot_general(
-        onehot, ov, (((0,), (0,)), ((), ())),
+        onehot, ov, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)              # [F, NB]
 
 
@@ -57,31 +51,24 @@ def time_bin(start, end, func, rate=None, *, n_funcs: int, n_bins: int,
              t0: float, t1: float, be: int = 256, interpret: bool = True):
     """start/end [N] f32, func [N] i32, rate [N] (weight/sec; default 1)
     → [n_funcs, n_bins] f32 rate-weighted overlap."""
-    N = start.shape[0]
+    n = start.shape[0]
     if rate is None:
         rate = jnp.ones_like(start)
-    nb_blocks = max(-(-N // be), 1)
-    pad = nb_blocks * be - N
-    if pad:
-        start = jnp.pad(start, (0, pad))
-        end = jnp.pad(end, (0, pad))
-        func = jnp.pad(func, (0, pad), constant_values=-1)
-        rate = jnp.pad(rate, (0, pad))
-    bin_w = (t1 - t0) / n_bins
+    nb_blocks = max(-(-n // be), 1)
+    pad = nb_blocks * be - n
+
+    def row(x, dtype, fill=0):
+        return jnp.pad(x.astype(dtype), (0, pad), constant_values=fill)[None]
 
     kern = functools.partial(_kernel, n_funcs=n_funcs, n_bins=n_bins,
-                             t0=t0, bin_w=bin_w)
+                             t0=t0, bin_w=(t1 - t0) / n_bins)
+    spec = pl.BlockSpec((1, be), lambda i: (0, i))
     return pl.pallas_call(
         kern,
         grid=(nb_blocks,),
-        in_specs=[
-            pl.BlockSpec((be,), lambda i: (i,)),
-            pl.BlockSpec((be,), lambda i: (i,)),
-            pl.BlockSpec((be,), lambda i: (i,)),
-            pl.BlockSpec((be,), lambda i: (i,)),
-        ],
+        in_specs=[spec, spec, spec, spec],
         out_specs=pl.BlockSpec((n_funcs, n_bins), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((n_funcs, n_bins), jnp.float32),
         interpret=interpret,
-    )(start.astype(jnp.float32), end.astype(jnp.float32),
-      func.astype(jnp.int32), rate.astype(jnp.float32))
+    )(row(start, jnp.float32), row(end, jnp.float32),
+      row(func, jnp.int32, -1), row(rate, jnp.float32))

@@ -53,9 +53,6 @@ def _cell_costs(cfg, shape, mesh, chips):
     cell = build_cell(cfg, shape, mesh)
     compiled = cell.lower(mesh).compile()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        # jax < 0.5 returns a one-element list of per-program dicts
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     coll = collective_stats(hlo, default_group=chips)
     return (float(cost.get("flops", 0.0)),

@@ -173,7 +173,6 @@ def build_compressed_dp_cell(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh,
     EXPERIMENTS.md §Perf; the production path remains FSDP-over-(pod,data).
     """
     from ..distributed.compression import pairwise_compressed_mean
-    from ..distributed.sharding import shard_map_compat
 
     assert "pod" in mesh.shape and shape.kind == "train"
     n_pods = mesh.shape["pod"]
@@ -224,9 +223,11 @@ def build_compressed_dp_cell(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh,
                     {k: (P("pod") if getattr(v, "ndim", 0) else P())
                      for k, v in batch.items()})
         out_specs = in_specs[:2] + (P(),)
-        return shard_map_compat(per_pod, mesh, in_specs, out_specs,
-                                manual_axes=frozenset({"pod"})
-                                )(params, opt_state, batch)
+        # replication checking off: the int8-wire collective is
+        # deliberately non-replicated
+        return jax.shard_map(per_pod, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False,
+                             axis_names={"pod"})(params, opt_state, batch)
 
     return Cell(
         name=f"{cfg.name}:{shape.name}:int8dp", fn=train_step,

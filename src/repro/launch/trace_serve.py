@@ -43,8 +43,11 @@ def main():
                     help="threads reserved for the interactive lane")
     args = ap.parse_args()
 
+    from ..compile_cache import use_compile_cache
     from ..core.scheduler import Scheduler, set_scheduler
     from ..serving.tracequery import serve
+
+    use_compile_cache()
 
     if args.workers is not None or args.interactive_workers is not None:
         set_scheduler(Scheduler(workers=args.workers,

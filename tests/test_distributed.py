@@ -85,7 +85,8 @@ SUBPROC = textwrap.dedent("""
     batch = {"tokens": tokens, "labels": labels}
     loss_1dev = float(model.loss(params, batch))
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rules = rules_for(cfg, mesh)
     specs = spec_tree(model.param_defs(), rules, mesh)
     pshard = jax.tree_util.tree_map(
@@ -121,9 +122,9 @@ SUBPROC_INT8DP = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.distributed.compression import pairwise_compressed_mean
-    from repro.distributed.sharding import shard_map_compat
 
-    mesh = jax.make_mesh((2,), ("pod",))
+    mesh = jax.make_mesh((2,), ("pod",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     g0 = jax.random.normal(jax.random.PRNGKey(0), (1000,)) * 0.01
     g1 = jax.random.normal(jax.random.PRNGKey(1), (1000,)) * 0.01
     g = jnp.stack([g0, g1])
@@ -132,7 +133,8 @@ SUBPROC_INT8DP = textwrap.dedent("""
         def per_pod(g):
             out, _ = pairwise_compressed_mean(g[0], "pod", 2)
             return out[None]
-        return shard_map_compat(per_pod, mesh, P("pod"), P("pod"))(g)
+        return jax.shard_map(per_pod, mesh=mesh, in_specs=P("pod"),
+                             out_specs=P("pod"), check_vma=False)(g)
     with mesh:
         out = jax.jit(f, in_shardings=NamedSharding(mesh, P("pod")))(g)
     want = np.asarray((g0 + g1) / 2)

@@ -320,20 +320,47 @@ def test_otf2j_rank_units(tmp_path):
                                   chunk_rows=50, per_process=True))
 
 
-def test_spawn_pool_end_to_end(trace_file, mem):
-    """The public API with a real spawn pool (pytest's __main__ is an
-    importable script, so the pool genuinely starts)."""
-    st = Trace.open(trace_file, streaming=True, chunk_rows=101,
+_SPAWN_SCRIPT = """
+import pickle, sys, warnings
+sys.path.insert(0, {src!r})
+from repro.core.trace import Trace
+
+if __name__ == "__main__":  # spawned workers re-import this file
+    st = Trace.open({path!r}, streaming=True, chunk_rows=101,
                     executor="parallel", processes=2, cache=False)
     with warnings.catch_warnings():
         warnings.simplefilter("error", RuntimeWarning)  # no degradation
-        prof = st.flat_profile(metrics=[EXC, INC])
-    assert_frames_equal(mem.flat_profile(metrics=[EXC, INC]), prof)
+        prof = st.flat_profile(metrics=["time.exc", "time.inc"])
     # the handle keeps its pool: a second op must not restart workers
     pool = st._pool
     assert pool is not None
-    assert_frames_equal(mem.load_imbalance(), st.load_imbalance())
+    imb = st.load_imbalance()
     assert st._pool is pool
+    with open({out!r}, "wb") as f:
+        pickle.dump((prof, imb), f)
+"""
+
+
+def test_spawn_pool_end_to_end(trace_file, mem, tmp_path):
+    """The public API with a real spawn pool.  Spawned workers re-import
+    ``__main__``, so the pool is started from a script file of its own: under
+    pytest-xdist the worker's ``__main__`` has no file and the pool would
+    (rightly) degrade to serial."""
+    import pickle
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    out = str(tmp_path / "results.pkl")
+    script = tmp_path / "spawn_e2e.py"
+    script.write_text(_SPAWN_SCRIPT.format(src=os.path.abspath(src),
+                                           path=trace_file, out=out))
+    r = subprocess.run([sys.executable, str(script)], capture_output=True,
+                       text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
+    with open(out, "rb") as f:
+        prof, imb = pickle.load(f)
+    assert_frames_equal(mem.flat_profile(metrics=[EXC, INC]), prof)
+    assert_frames_equal(mem.load_imbalance(), imb)
 
 
 def test_traceset_members_share_one_pool(tmp_path):
