@@ -20,7 +20,10 @@ tiebreak.  See docs/kernels.md for the full precision contract.
 
 This module is numpy-in / numpy-out — jax is imported lazily inside the
 kernel calls so merely importing the core never pulls the accelerator
-stack.
+stack.  The sort is timed as the ``accel.sort`` span, and each adapter
+call (operand conversion, upload, dispatch, wait and fetch) as an
+``accel.kernel`` span; the ``accel.h2d_bytes`` counter adds up the
+bytes its host operands take on the device.
 """
 
 from __future__ import annotations
@@ -29,8 +32,10 @@ from typing import Tuple
 
 import numpy as np
 
+from ..runtime import tracer
+
 __all__ = ["canonical_order", "alpha_positions", "block_size", "seg_sum",
-           "pair_sum", "hist_counts"]
+           "pair_sum", "hist_counts", "count_upload"]
 
 
 # One-hot tile bound, in f32 elements: a block builds [rows, BE] one-hot and
@@ -79,11 +84,22 @@ def canonical_order(start, end, proc, code, value) -> np.ndarray:
     ``inv[raw_code]``), and ``value`` as the final tiebreak.  Records equal
     on *all* keys are interchangeable, so any two paths that hold the same
     record multiset feed the kernel bit-identical blocks."""
-    return np.lexsort((np.asarray(value, np.float64),
-                       np.asarray(code, np.int64),
-                       np.asarray(proc, np.int64),
-                       np.asarray(end, np.float64),
-                       np.asarray(start, np.float64)))
+    with tracer.span("accel.sort"):
+        return np.lexsort((np.asarray(value, np.float64),
+                           np.asarray(code, np.int64),
+                           np.asarray(proc, np.int64),
+                           np.asarray(end, np.float64),
+                           np.asarray(start, np.float64)))
+
+
+def count_upload(*operands) -> None:
+    """Add the bytes that one kernel call's host operands take on the
+    device (in the dtypes jax gives them) to ``accel.h2d_bytes``."""
+    import jax
+    tracer.counter("accel.h2d_bytes", sum(
+        int(np.size(a)) * np.dtype(
+            jax.dtypes.canonicalize_dtype(np.asarray(a).dtype)).itemsize
+        for a in operands))
 
 
 def seg_sum(code: np.ndarray, values: np.ndarray, n_seg: int) -> np.ndarray:
@@ -100,11 +116,14 @@ def seg_sum(code: np.ndarray, values: np.ndarray, n_seg: int) -> np.ndarray:
     if n_seg <= 0 or values.shape[1] == 0:
         out = np.zeros((max(n_seg, 0), values.shape[1]))
         return out[:, 0] if squeeze else out
-    # lane-major: the kernel takes one value row per metric
-    out = np.asarray(segment_sum_matrix(
-        jnp.asarray(np.asarray(code, np.int32)),
-        jnp.asarray(np.ascontiguousarray(values.T, np.float32)),
-        n_seg=int(n_seg), be=block_size(len(values), n_seg)), np.float64)
+    with tracer.span("accel.kernel", kernel="seg_sum"):
+        # lane-major: the kernel takes one value row per metric
+        ops = (np.asarray(code, np.int32),
+               np.ascontiguousarray(values.T, np.float32))
+        out = np.asarray(segment_sum_matrix(
+            *(jnp.asarray(a) for a in ops), n_seg=int(n_seg),
+            be=block_size(len(values), n_seg)), np.float64)
+    count_upload(*ops)
     return out[:, 0] if squeeze else out
 
 
@@ -117,12 +136,14 @@ def pair_sum(a: np.ndarray, b: np.ndarray, w: np.ndarray, n_a: int,
     import jax.numpy as jnp
 
     from ..kernels.ops import pair_sum_matrix
-    return np.asarray(pair_sum_matrix(
-        jnp.asarray(np.asarray(a, np.int32)),
-        jnp.asarray(np.asarray(b, np.int32)),
-        jnp.asarray(np.asarray(w, np.float32)),
-        n_a=int(n_a), n_b=int(n_b),
-        be=block_size(len(np.asarray(a)), n_a + n_b)), np.float64)
+    with tracer.span("accel.kernel", kernel="pair_sum"):
+        ops = (np.asarray(a, np.int32), np.asarray(b, np.int32),
+               np.asarray(w, np.float32))
+        out = np.asarray(pair_sum_matrix(
+            *(jnp.asarray(x) for x in ops), n_a=int(n_a), n_b=int(n_b),
+            be=block_size(len(ops[0]), n_a + n_b)), np.float64)
+    count_upload(*ops)
+    return out
 
 
 def hist_counts(idx: np.ndarray, n_bins: int) -> np.ndarray:
@@ -135,8 +156,10 @@ def hist_counts(idx: np.ndarray, n_bins: int) -> np.ndarray:
     import jax.numpy as jnp
 
     from ..kernels.ops import histogram_counts
-    coords = np.asarray(idx, np.float64) + 0.5
-    out = np.asarray(histogram_counts(
-        jnp.asarray(coords, jnp.float32), n_bins=int(n_bins),
-        be=block_size(len(coords), n_bins)))
+    with tracer.span("accel.kernel", kernel="hist_bin"):
+        coords = np.asarray(idx, np.float64) + 0.5
+        out = np.asarray(histogram_counts(
+            jnp.asarray(coords, jnp.float32), n_bins=int(n_bins),
+            be=block_size(len(coords), n_bins)))
+    count_upload(coords)
     return np.rint(out).astype(np.int64)

@@ -48,8 +48,9 @@ from .constants import ENTER, ET, INSTANT, LEAVE, NAME, PROC, TS
 from .frame import Categorical, EventFrame
 from .streaming import (CallBlock, CallStitcher, Chunk, GlobalNames,
                         StreamAgg, StreamContext, StreamStats,
-                        StreamingUnsupported, _steps_hints, fold_frames,
-                        iter_chunks_fallback, mask_frames, stats_from_frames)
+                        StreamingUnsupported, _steps_hints, finalize,
+                        fold_frames, iter_chunks_fallback, mask_frames,
+                        stats_from_frames)
 from ..parallel_util import resolve_processes, spawn_unsafe_reason
 
 __all__ = ["execute_parallel", "plan_units", "ParallelDegraded"]
@@ -339,7 +340,7 @@ def _merge_results(agg: StreamAgg, stats: Optional[StreamStats],
     open_calls = (np.asarray([f[0] for f in open_frames], np.int64),
                   np.asarray([f[1] for f in open_frames], np.int64))
     ctx = StreamContext(names, stats, open_calls, proc_max)
-    return agg.result(ctx)
+    return finalize(agg, ctx)
 
 
 def _prune_units(units: List[Any], hints: registry.PlanHints) -> List[Any]:

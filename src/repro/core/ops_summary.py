@@ -22,6 +22,7 @@ from .frame import Categorical, EventFrame
 from .registry import (get_backend, op_backends, register_backend,
                        register_op, register_streaming)
 from .streaming import StreamAgg, StreamingUnsupported, grow_to
+from ..runtime import tracer
 
 
 # ---------------------------------------------------------------------------
@@ -300,10 +301,13 @@ def _pallas_profile(starts, ends, rate, name_codes, edges, nf) -> np.ndarray:
         # directly): every overlap is zero — dividing by bw would turn that
         # into NaN where the numpy backend returns zeros
         return np.zeros((num_bins, nf))
-    return np.asarray(time_profile_matrix(
-        (starts - t0) / bw, (ends - t0) / bw, name_codes, rate * bw,
-        n_funcs=nf, n_bins=num_bins, t0=0.0, t1=float(num_bins),
-        be=accel.block_size(len(starts), nf + num_bins))).T
+    with tracer.span("accel.kernel", kernel="time_bin"):
+        ops = ((starts - t0) / bw, (ends - t0) / bw, name_codes, rate * bw)
+        out = np.asarray(time_profile_matrix(
+            *ops, n_funcs=nf, n_bins=num_bins, t0=0.0, t1=float(num_bins),
+            be=accel.block_size(len(starts), nf + num_bins))).T
+    accel.count_upload(*ops)
+    return out
 
 
 @register_time_profile_backend("numpy")

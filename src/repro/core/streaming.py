@@ -45,6 +45,7 @@ from . import registry, structure
 from .constants import (DERIVED_COLUMNS, ENTER, ET, EXC, INC, LEAVE, MATCH,
                         NAME, PARENT, PROC, THREAD, TS)
 from .frame import Categorical, EventFrame, concat
+from ..runtime import tracer
 
 __all__ = ["StreamingTrace", "LiveTrace", "Watermark", "LiveResult",
            "StreamingUnsupported", "StreamAgg",
@@ -604,20 +605,21 @@ def stats_from_frames(frames: Iterator[EventFrame]) -> StreamStats:
         n = len(frame)
         if n == 0:
             continue
-        st.n_events += n
-        ts = np.asarray(frame[TS], np.float64)
-        st.ts_min = min(st.ts_min, float(ts.min()))
-        st.ts_max = max(st.ts_max, float(ts.max()))
-        st.proc_max = max(st.proc_max,
-                          int(np.asarray(frame[PROC], np.int64).max()))
-        if MSG_SIZE in frame:
-            sends = frame.cat(NAME).mask_eq(MPI_SEND)
-            if np.any(sends):
-                sz = np.nan_to_num(
-                    np.asarray(frame[MSG_SIZE], np.float64)[sends])
-                st.n_sends += int(sends.sum())
-                st.size_min = min(st.size_min, float(sz.min()))
-                st.size_max = max(st.size_max, float(sz.max()))
+        with tracer.span("stream.stats"):
+            st.n_events += n
+            ts = np.asarray(frame[TS], np.float64)
+            st.ts_min = min(st.ts_min, float(ts.min()))
+            st.ts_max = max(st.ts_max, float(ts.max()))
+            st.proc_max = max(st.proc_max,
+                              int(np.asarray(frame[PROC], np.int64).max()))
+            if MSG_SIZE in frame:
+                sends = frame.cat(NAME).mask_eq(MPI_SEND)
+                if np.any(sends):
+                    sz = np.nan_to_num(
+                        np.asarray(frame[MSG_SIZE], np.float64)[sends])
+                    st.n_sends += int(sends.sum())
+                    st.size_min = min(st.size_min, float(sz.min()))
+                    st.size_max = max(st.size_max, float(sz.max()))
     return st
 
 
@@ -635,11 +637,22 @@ def fold_frames(frames: Iterator[EventFrame], agg: StreamAgg,
     for frame in frames:
         if len(frame) == 0:
             continue
-        gcodes = names.encode(frame.cat(NAME))
-        calls = stitcher.push_chunk(frame, gcodes) if stitcher else None
+        with tracer.span("stream.intern"):
+            gcodes = names.encode(frame.cat(NAME))
+        calls = None
+        if stitcher:
+            with tracer.span("stream.stitch"):
+                calls = stitcher.push_chunk(frame, gcodes)
         proc_max = max(proc_max, int(np.asarray(frame[PROC], np.int64).max()))
-        agg.update(Chunk(frame, gcodes, calls, names))
+        with tracer.span("stream.update"):
+            agg.update(Chunk(frame, gcodes, calls, names))
     return proc_max
+
+
+def finalize(agg: StreamAgg, ctx: StreamContext) -> Any:
+    """``agg.result(ctx)``, timed as the ``stream.finalize`` span."""
+    with tracer.span("stream.finalize"):
+        return agg.result(ctx)
 
 
 def execute_streaming(handle: "StreamingTrace", steps: Sequence,
@@ -703,7 +716,7 @@ def execute_streaming(handle: "StreamingTrace", steps: Sequence,
     open_calls = (stitcher.open_calls() if stitcher
                   else (np.empty(0, np.int64), np.empty(0, np.int64)))
     ctx = StreamContext(names, stats, open_calls, proc_max)
-    return agg.result(ctx)
+    return finalize(agg, ctx)
 
 
 # ---------------------------------------------------------------------------
@@ -825,7 +838,7 @@ def _execute_live_incremental(handle: "LiveTrace", steps: Sequence,
                       else (np.empty(0, np.int64), np.empty(0, np.int64)))
         proc_max = entry.proc_max
     ctx = StreamContext(final_names, None, open_calls, proc_max)
-    return final_agg.result(ctx)
+    return finalize(final_agg, ctx)
 
 
 class Watermark:
@@ -958,7 +971,8 @@ class StreamingTrace:
             kw.setdefault("report", self._ingest)
         from .cancellation import check_cancelled
         for p in paths:
-            spec = registry.resolve_reader(p, self.format)
+            with tracer.span("read.open"):   # format sniffing
+                spec = registry.resolve_reader(p, self.format)
             if spec.iter_chunks is not None:
                 frames = spec.iter_chunks(p, self.chunk_rows, hints, **kw)
             else:

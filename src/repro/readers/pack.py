@@ -91,6 +91,7 @@ from ..core.registry import (PlanHints, RowSpan, even_groups,
                              register_chunked, register_reader,
                              register_units)
 from ..core.trace import Trace
+from ..runtime import tracer
 
 __all__ = ["write_pack", "read_pack", "PackWriter", "read_footer",
            "content_id", "io_stats", "reset_io_stats", "verify_pack",
@@ -136,12 +137,22 @@ _ON_ERROR_MODES = ("strict", "skip_chunk", "salvage")
 
 # ---------------------------------------------------------------------------
 # io accounting (tests / benchmarks assert pushdown actually skips chunks,
-# and the fault suite asserts salvage quarantines exactly the damaged ones)
+# and the fault suite asserts salvage quarantines exactly the damaged ones):
+# process-wide ``read.<key>`` counters of the runtime tracer
 # ---------------------------------------------------------------------------
 
-_IO_STATS = {"chunks_read": 0, "chunks_skipped": 0, "chunks_quarantined": 0,
-             "footers_rebuilt": 0, "sidecars_dropped": 0,
-             "verify_cache_hits": 0}
+_IO_KEYS = ("chunks_read", "chunks_skipped", "chunks_quarantined",
+            "footers_rebuilt", "sidecars_dropped", "verify_cache_hits")
+#: counter readings at the last reset_io_stats()
+_IO_BASE: Dict[str, int] = dict.fromkeys(_IO_KEYS, 0)
+
+
+def _io_add(key: str, n: int = 1) -> None:
+    tracer.counter("read." + key, n)
+
+
+def _io(key: str) -> int:
+    return tracer.count("read." + key)
 
 #: aspects ("chunks", "sidecar") whose CRC sweep passed, keyed by
 #: (abspath, size, mtime_ns, inode, committed-group count) — a
@@ -173,13 +184,13 @@ def io_stats() -> Dict[str, int]:
     (advisory; parallel pool workers count in their own process):
     footer-index chunks read vs skipped by pushdown, plus the fault-path
     counters — chunks quarantined by CRC/scan failure, footers rebuilt by
-    trailer scan, sidecars dropped as corrupt."""
-    return dict(_IO_STATS)
+    trailer scan, sidecars dropped as corrupt.  A view of the tracer's
+    ``read.*`` counters, which themselves never reset."""
+    return {k: _io(k) - _IO_BASE[k] for k in _IO_KEYS}
 
 
 def reset_io_stats() -> None:
-    for k in _IO_STATS:
-        _IO_STATS[k] = 0
+    _IO_BASE.update({k: _io(k) for k in _IO_KEYS})
 
 
 # ---------------------------------------------------------------------------
@@ -813,8 +824,8 @@ def _salvage_footer(path: str) -> dict:
                       f"quarantined chunks; placeholders substituted",
                       RuntimeWarning, stacklevel=3)
     missing = groups[-1]["seq"] + 1 - len(groups)
-    _IO_STATS["chunks_quarantined"] += missing
-    _IO_STATS["footers_rebuilt"] += 1
+    _io_add("chunks_quarantined", missing)
+    _io_add("footers_rebuilt")
     if missing:
         warnings.warn(f"{path}: {missing} chunk group(s) unrecoverable "
                       f"(CRC mismatch or lost bytes); salvaging "
@@ -1020,7 +1031,7 @@ def _resolve_chunks(path: str, on_error: str) -> Tuple[dict, List[dict], bool]:
     st = os.stat(path)
     key = _verify_key(path, st, len(footer["chunks"]))
     if "chunks" in _VERIFIED_CLEAN.get(key, ()):
-        _IO_STATS["verify_cache_hits"] += 1
+        _io_add("verify_cache_hits")
         return footer, list(footer["chunks"]), True
     size = st.st_size
     good: List[dict] = []
@@ -1033,7 +1044,7 @@ def _resolve_chunks(path: str, on_error: str) -> Tuple[dict, List[dict], bool]:
             else:
                 bad += 1
     if bad:
-        _IO_STATS["chunks_quarantined"] += bad
+        _io_add("chunks_quarantined", bad)
         warnings.warn(f"{path}: quarantined {bad} chunk group(s) failing "
                       f"CRC; {len(good)} intact group(s) kept",
                       RuntimeWarning, stacklevel=3)
@@ -1085,15 +1096,13 @@ def repair_pack(src: str, dst: str,
     ``src`` (footer loss and CRC-failing groups tolerated), then write a
     fresh, fully-checksummed pack with a re-derived sidecar at ``dst``.
     Returns a report with rows recovered and groups quarantined."""
-    before = dict(_IO_STATS)
+    quar0, rebuilt0 = _io("chunks_quarantined"), _io("footers_rebuilt")
     t = read_pack(src, on_error="salvage", sidecar=False)
     write_pack(t, dst, chunk_rows=chunk_rows or DEFAULT_PACK_CHUNK_ROWS)
     return {"src": os.fspath(src), "dst": os.fspath(dst),
             "rows_recovered": len(t),
-            "chunks_quarantined": (_IO_STATS["chunks_quarantined"]
-                                   - before["chunks_quarantined"]),
-            "footer_rebuilt": bool(_IO_STATS["footers_rebuilt"]
-                                   - before["footers_rebuilt"])}
+            "chunks_quarantined": _io("chunks_quarantined") - quar0,
+            "footer_rebuilt": bool(_io("footers_rebuilt") - rebuilt0)}
 
 
 # ---------------------------------------------------------------------------
@@ -1243,7 +1252,7 @@ def _open_sidecar(path: str, footer: dict, on_error: str = "strict"
                                     shape=(rows,))
                 for c in meta}
     except (OSError, ValueError) as e:
-        _IO_STATS["sidecars_dropped"] += 1
+        _io_add("sidecars_dropped")
         warnings.warn(f"{path}: structure sidecar unreadable ({e}); falling "
                       f"back to derive_structure", RuntimeWarning,
                       stacklevel=3)
@@ -1260,7 +1269,7 @@ def _open_sidecar(path: str, footer: dict, on_error: str = "strict"
                 ok = hi <= len(mm) and zlib.crc32(mm[lo:hi]) == \
                     footer["sidecar_crc"]
             if not ok:
-                _IO_STATS["sidecars_dropped"] += 1
+                _io_add("sidecars_dropped")
                 warnings.warn(f"{path}: structure sidecar fails CRC; "
                               f"falling back to derive_structure",
                               RuntimeWarning, stacklevel=3)
@@ -1274,7 +1283,7 @@ def _open_sidecar(path: str, footer: dict, on_error: str = "strict"
             idx = np.asarray(side[key], np.int64)
             if int(idx.max(initial=-1)) >= rows or \
                     int(idx.min(initial=0)) < -1:
-                _IO_STATS["sidecars_dropped"] += 1
+                _io_add("sidecars_dropped")
                 warnings.warn(
                     f"{path}: structure sidecar has out-of-range row "
                     f"indices (corrupt?); falling back to "
@@ -1361,7 +1370,7 @@ def read_pack(path: str, label: Optional[str] = None,
     from ..core.errors import IngestReport
     path = os.fspath(path)
     report = report if report is not None else IngestReport()
-    quar0 = _IO_STATS["chunks_quarantined"]
+    quar0 = _io("chunks_quarantined")
     if live or upto_rows is not None:
         footer, chunks = _resolve_live(path, upto_rows)
         intact = False  # live prefixes carry no sidecar; derive lazily
@@ -1370,7 +1379,7 @@ def read_pack(path: str, label: Optional[str] = None,
     names = _name_table(footer)
     rows = sum(c["hi"] - c["lo"] for c in chunks)
     report.begin(path)
-    q = _IO_STATS["chunks_quarantined"] - quar0
+    q = _io("chunks_quarantined") - quar0
     if q:
         report.skip(path, q, "",
                     "chunk groups quarantined (CRC/structure fault)")
@@ -1464,63 +1473,75 @@ def iter_chunks_pack(path: str, chunk_rows: int,
     ``live`` / ``upto_rows`` follow :func:`read_pack`: stream the
     committed prefix of a still-growing shard, pinned to a watermark.
     """
-    path = os.fspath(path)
-    quar0 = _IO_STATS["chunks_quarantined"]
-    if live or upto_rows is not None:
-        footer, fchunks = _resolve_live(path, upto_rows)
-        intact = False
-    else:
-        footer, fchunks, intact = _resolve_chunks(path, on_error)
-    names = _name_table(footer)
-    total = sum(c["hi"] - c["lo"] for c in fchunks)
-    if report is not None and row_range is None:
-        report.begin(path)
-        q = _IO_STATS["chunks_quarantined"] - quar0
-        if q:
-            report.skip(path, q, "",
-                        "chunk groups quarantined (CRC/structure fault)")
-        report.add_rows(path, total)
-    if footer["version"] == 1:
-        cols = _open_columns_v1(path, footer)
-    elif fchunks:
-        cols = _GroupColumnSource(path, fchunks, footer["has_thread"],
-                                  footer["has_messages"])
-    else:
-        cols = {}  # nothing committed yet — no bytes to map
-    side = (_open_sidecar(path, footer, on_error)
-            if sidecar and intact else None)
-    r_lo, r_hi = (0, total) if row_range is None else (
-        int(row_range[0]), int(row_range[1]))
-    # pushdown at footer-chunk granularity, then coalesce surviving runs
-    runs: List[List[int]] = []
-    for ch in fchunks:
-        lo, hi = max(ch["lo"], r_lo), min(ch["hi"], r_hi)
-        if hi <= lo:
-            continue
-        if not _admits_chunk(ch, hints):
-            _IO_STATS["chunks_skipped"] += 1
-            continue
-        _IO_STATS["chunks_read"] += 1
-        if runs and runs[-1][1] == lo:
-            runs[-1][1] = hi
+    # opening the file for this pass: footer, column and sidecar maps,
+    # pushdown over the footer index
+    with tracer.span("read.open"):
+        path = os.fspath(path)
+        reporting = report is not None and row_range is None
+        quar0 = _io("chunks_quarantined") if reporting else 0
+        if live or upto_rows is not None:
+            footer, fchunks = _resolve_live(path, upto_rows)
+            intact = False
         else:
-            runs.append([lo, hi])
+            footer, fchunks, intact = _resolve_chunks(path, on_error)
+        names = _name_table(footer)
+        total = sum(c["hi"] - c["lo"] for c in fchunks)
+        if reporting:
+            report.begin(path)
+            q = _io("chunks_quarantined") - quar0
+            if q:
+                report.skip(path, q, "",
+                            "chunk groups quarantined (CRC/structure fault)")
+            report.add_rows(path, total)
+        if footer["version"] == 1:
+            cols = _open_columns_v1(path, footer)
+        elif fchunks:
+            cols = _GroupColumnSource(path, fchunks, footer["has_thread"],
+                                      footer["has_messages"])
+        else:
+            cols = {}  # nothing committed yet — no bytes to map
+        side = (_open_sidecar(path, footer, on_error)
+                if sidecar and intact else None)
+        r_lo, r_hi = (0, total) if row_range is None else (
+            int(row_range[0]), int(row_range[1]))
+        # pushdown at footer-chunk granularity, then coalesce surviving runs
+        runs: List[List[int]] = []
+        read = skipped = 0
+        for ch in fchunks:
+            lo, hi = max(ch["lo"], r_lo), min(ch["hi"], r_hi)
+            if hi <= lo:
+                continue
+            if not _admits_chunk(ch, hints):
+                skipped += 1
+                continue
+            read += 1
+            if runs and runs[-1][1] == lo:
+                runs[-1][1] = hi
+            else:
+                runs.append([lo, hi])
+        _io_add("chunks_read", read)
+        _io_add("chunks_skipped", skipped)
     for lo, hi in runs:
         for s in range(lo, hi, chunk_rows):
             e = min(s + chunk_rows, hi)
-            ev = _frame_slice(cols, names, s, e, uniform=True)
-            mask = _row_mask(ev, hints)
-            if mask is None:
-                if side is not None:
-                    _localize(side, ev, s, e)
-                yield ev
-            else:
-                if not np.any(mask):
+            # the span closes before the yield: the consumer's time is
+            # not the reader's
+            with tracer.span("read.chunk"):
+                ev = _frame_slice(cols, names, s, e, uniform=True)
+                mask = _row_mask(ev, hints)
+                if mask is None:
+                    if side is not None:
+                        _localize(side, ev, s, e)
+                elif np.any(mask):
+                    # row filtering invalidates localized structure
+                    # indices — the stitcher re-derives on the filtered
+                    # chunk, exactly like parse-time pushdown in the text
+                    # readers
+                    ev = ev.mask(mask)
+                else:
                     continue
-                # row filtering invalidates localized structure indices —
-                # the stitcher re-derives on the filtered chunk, exactly
-                # like parse-time pushdown in the text readers
-                yield ev.mask(mask)
+            tracer.counter("read.rows", len(ev))
+            yield ev
 
 
 @register_units("pack")

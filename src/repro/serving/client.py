@@ -37,6 +37,7 @@ from typing import Any, Dict, List, Optional, Sequence
 
 from ..core import registry
 from ..core.filters import Filter
+from ..runtime import tracer
 from . import protocol
 
 __all__ = ["RemoteError", "ServiceClient", "RemoteTrace", "RemoteTraceSet",
@@ -104,7 +105,18 @@ class ServiceClient:
 
     # -- transport ---------------------------------------------------------
     def _request(self, method: str, path: str,
-                 payload: Optional[dict] = None) -> dict:
+                 payload: Optional[dict] = None, *,
+                 result: bool = False) -> dict:
+        """One round trip, timed as the ``client.request`` span: encode,
+        send, wait, decode (with ``result``, ``out["result"]`` too)."""
+        with tracer.span("client.request"):
+            out = self._round_trip(method, path, payload)
+            if result and "result" in out:
+                out["result"] = protocol.decode_value(out["result"])
+            return out
+
+    def _round_trip(self, method: str, path: str,
+                    payload: Optional[dict]) -> dict:
         body = json.dumps(payload).encode() if payload is not None else None
         idempotent = (method == "GET" or path in _IDEMPOTENT_POSTS)
         attempts = (self.retries + 1) if idempotent else 2
@@ -262,13 +274,14 @@ class ServiceClient:
         if deadline_ms is not None:
             payload["deadline_ms"] = float(deadline_ms)
         endpoint = "/setquery" if open_spec["mode"] == "set" else "/query"
-        out = self._request("POST", endpoint, payload)
+        out = self._request("POST", endpoint, payload,
+                            result=not digest_only)
         self.last_meta = {k: out.get(k) for k in
                           ("digest", "cached", "coalesced", "elapsed_ms",
                            "tenant")}
         if digest_only:
             return out["digest"]
-        return protocol.decode_value(out["result"])
+        return out["result"]
 
     def live_poll(self, open_spec: dict, op: str, args=(), kwargs=None,
                   *, steps: Optional[List[dict]] = None,

@@ -46,6 +46,7 @@ protocol in the library's own query-chain API.
 from __future__ import annotations
 
 import asyncio
+import contextvars
 import hashlib
 import json
 import threading
@@ -57,6 +58,7 @@ from typing import Any, Dict, List, Optional, Tuple
 from ..core import plancache, registry
 from ..core.cancellation import CancelToken, cancel_scope
 from ..core.scheduler import Scheduler, get_scheduler
+from ..runtime import tracer
 from . import protocol
 from .protocol import ProtocolError, canonical_json
 
@@ -243,6 +245,10 @@ class HandlePool:
         ``breaker_cooldown`` seconds instead of re-burning a lane thread
         on a source that cannot open.  One probe is admitted when the
         cooldown lapses; a successful open resets the breaker."""
+        with tracer.span("service.handle"):
+            return self._get(spec)
+
+    def _get(self, spec: dict) -> _Handle:
         key = hashlib.sha256(canonical_json(spec).encode()).hexdigest()
         try:
             ident = self._ident(spec["paths"])
@@ -480,29 +486,53 @@ class TraceService:
                  cache_flag, digest_only: bool) -> dict:
         """Runs on a scheduler lane thread: build the plan over the pooled
         handle, execute, encode."""
-        q = protocol.apply_steps(handle.query(), steps)
         kw = dict(kwargs)
         if handle.kind != "set" and cache_flag is not None:
             # forward the client's cache choice to the library-level plan
             # cache (streaming sources participate by default)
             kw["cache"] = cache_flag
-        t0 = time.perf_counter()
-        if handle.serialized:
-            with handle.lock:
+        with tracer.span("service.execute") as run:
+            q = protocol.apply_steps(handle.query(), steps)
+            if handle.serialized:
+                with handle.lock:
+                    value = q.run(op, *args, **kw)
+            else:
                 value = q.run(op, *args, **kw)
-        else:
-            value = q.run(op, *args, **kw)
-        elapsed = time.perf_counter() - t0
-        out = {"ok": True, "digest": protocol.result_digest(value),
-               "elapsed_ms": round(elapsed * 1e3, 3)}
-        if not digest_only:
-            out["result"] = protocol.encode_value(value)
+        return self._encode(value, run, digest_only, {"ok": True})
+
+    @staticmethod
+    def _encode(value, run: "tracer.span", digest_only: bool,
+                out: dict) -> dict:
+        """The response body: ``out`` plus the result's digest, the
+        execution's ``elapsed_ms`` and (unless ``digest_only``) the
+        encoded result."""
+        with tracer.span("service.encode"):
+            out["digest"] = protocol.result_digest(value)
+            out["elapsed_ms"] = round(run.duration_ns / 1e6, 3)
+            if not digest_only:
+                out["result"] = protocol.encode_value(value)
         return out
+
+    def _on_lane(self, lane: str, fn) -> "asyncio.Future":
+        """Run ``fn`` on a scheduler lane thread inside this request's
+        context, so the lane's spans carry its request id and parent;
+        ``service.queue`` times the wait for the thread."""
+        queued = tracer.begin("service.queue")
+
+        def run():
+            queued.end()
+            return fn()
+        return asyncio.get_running_loop().run_in_executor(
+            self.scheduler.lane(lane), contextvars.copy_context().run, run)
 
     async def query(self, payload: dict, set_scope: bool = False) -> dict:
         """Execute one wire request; returns the JSON-able response body.
         Raises :class:`ServiceError` for refusals and
         :class:`ProtocolError` for malformed requests."""
+        with tracer.span("service.request"):
+            return await self._query(payload, set_scope)
+
+    async def _query(self, payload: dict, set_scope: bool) -> dict:
         tenant = self._tenant(payload if isinstance(payload, dict) else {})
         self._count(tenant, "requests")
         if self.draining:
@@ -553,7 +583,8 @@ class TraceService:
                                f"requests (limit {self.per_tenant * 4})")
         self._tenant_waiting[tenant] = waiting + 1
         try:
-            await self._sem(tenant).acquire()
+            with tracer.span("service.admit"):
+                await self._sem(tenant).acquire()
         finally:
             self._tenant_waiting[tenant] -= 1
 
@@ -583,7 +614,7 @@ class TraceService:
             budget.  On expiry the 504 goes out immediately; the lane
             thread sees the cancelled token at its next chunk boundary
             and frees itself cooperatively."""
-            aw = loop.run_in_executor(self.scheduler.lane(lane), fn)
+            aw = self._on_lane(lane, fn)
             if deadline is None:
                 return await aw
             remaining = deadline - (time.monotonic() - t_start)
@@ -709,10 +740,9 @@ class TraceService:
                     f"(min_advance_rows={min_advance}); poll slower",
                     extra={"retry_after_ms": 250,
                            "watermark": wm.as_dict()})
-            q = protocol.apply_steps(lt.query(), steps)
-            t0 = time.perf_counter()
-            value = q.run(op, *args, **kwargs)
-            elapsed = time.perf_counter() - t0
+            with tracer.span("service.execute") as run:
+                q = protocol.apply_steps(lt.query(), steps)
+                value = q.run(op, *args, **kwargs)
             if sess is None:
                 sess = self._live_sessions[skey] = {
                     "rows": 0, "polls": 0, "stalls": 0, "served_at": 0.0}
@@ -721,9 +751,7 @@ class TraceService:
             sess["served_at"] = time.time()
             out = {"ok": True, "watermark": wm.as_dict(),
                    "advanced_rows": advanced, "session": skey[2],
-                   "partial": False,
-                   "digest": protocol.result_digest(value),
-                   "elapsed_ms": round(elapsed * 1e3, 3)}
+                   "partial": False}
             if cov is not None:
                 out["coverage"] = cov.as_dict()
                 if cov.degraded:
@@ -731,9 +759,7 @@ class TraceService:
                     # named in the response, never silently dropped
                     out["partial"] = True
                     out["missing_ranks"] = list(cov.missing)
-            if not digest_only:
-                out["result"] = protocol.encode_value(value)
-            return out
+            return self._encode(value, run, digest_only, out)
 
     async def live(self, payload: dict) -> dict:
         """One poll of a live session: refresh the committed prefix,
@@ -741,6 +767,10 @@ class TraceService:
         the survivors, and annotate the result with watermark + coverage.
         Degraded liveset coverage comes back ``partial: True`` (wire
         status 206)."""
+        with tracer.span("service.request"):
+            return await self._live(payload)
+
+    async def _live(self, payload: dict) -> dict:
         tenant = self._tenant(payload if isinstance(payload, dict) else {})
         self._count(tenant, "requests")
         if self.draining:
@@ -762,7 +792,8 @@ class TraceService:
                                f"requests (limit {self.per_tenant * 4})")
         self._tenant_waiting[tenant] = waiting + 1
         try:
-            await self._sem(tenant).acquire()
+            with tracer.span("service.admit"):
+                await self._sem(tenant).acquire()
         finally:
             self._tenant_waiting[tenant] -= 1
         self._active += 1
@@ -773,10 +804,9 @@ class TraceService:
         skey = (tenant,
                 hashlib.sha256(canonical_json(open_spec).encode())
                 .hexdigest(), session)
-        loop = asyncio.get_running_loop()
         try:
-            result = await loop.run_in_executor(
-                self.scheduler.lane("interactive"),
+            result = await self._on_lane(
+                "interactive",
                 lambda: self._poll_live(open_spec, op, steps, args, kwargs,
                                         min_advance, skey, digest_only))
             self._count(tenant, "executed")
@@ -822,7 +852,8 @@ class TraceService:
                             for t, c in self.tenant_counters.items()},
                 "plancache": plancache.stats(),
                 "scheduler": self.scheduler.stats(),
-                "handles": self.handles.stats()}
+                "handles": self.handles.stats(),
+                "telemetry": tracer.snapshot()}
 
     async def drain(self, timeout: Optional[float] = None) -> bool:
         """Refuse new queries and wait for in-flight ones to finish.
