@@ -941,6 +941,7 @@ class StreamingTrace:
         self._stats0: Optional[StreamStats] = None  # no-selection stats
         self._pool = None  # SharedPool, possibly shared across a TraceSet
         self._units_cache: dict = {}  # work-unit plans per (paths, workers)
+        self._sniffed: dict = {}  # path -> (stat token, ReaderSpec)
         from .errors import IngestReport
         self._ingest = IngestReport()  # filled by tolerant (on_error) reads
 
@@ -962,7 +963,8 @@ class StreamingTrace:
         procs = set(hints.procs) if hints and hints.procs is not None else None
         bounds = hints.proc_bounds if hints else None
         paths = select_shards(self.paths, self.format, procs=procs,
-                              proc_bounds=bounds)
+                              proc_bounds=bounds,
+                              resolve=lambda p: self._sniff(p)[0])
         kw = dict(self.reader_kwargs)
         if "on_error" in kw:
             # tolerant read: route per-record skip counts into this
@@ -972,9 +974,12 @@ class StreamingTrace:
         from .cancellation import check_cancelled
         for p in paths:
             with tracer.span("read.open"):   # format sniffing
-                spec = registry.resolve_reader(p, self.format)
+                spec, st = self._sniff(p)
             if spec.iter_chunks is not None:
-                frames = spec.iter_chunks(p, self.chunk_rows, hints, **kw)
+                # the sniff's stat also revalidates a pack's kept open
+                pkw = kw if st is None or spec.name != "pack" else \
+                    dict(kw, st=st)
+                frames = spec.iter_chunks(p, self.chunk_rows, hints, **pkw)
             else:
                 frames = iter_chunks_fallback(p, self.chunk_rows, hints,
                                               spec.read, **kw)
@@ -983,6 +988,28 @@ class StreamingTrace:
                 # 504) frees its lane thread at the next chunk boundary
                 check_cancelled()
                 yield frame
+
+    def _sniff(self, path: str) -> tuple:
+        """``(reader, stat)`` of ``path``: with ``format="auto"`` on a
+        regular file, the reader is sniffed once while the file's stat
+        token holds, and ``stat`` is the one ``os.stat`` that checked it;
+        otherwise ``stat`` is None."""
+        if self.format != "auto":
+            return registry.resolve_reader(path, self.format), None
+        import os
+        import stat
+        try:
+            st = os.stat(path)
+        except OSError:
+            st = None
+        if st is None or not stat.S_ISREG(st.st_mode):
+            return registry.resolve_reader(path, self.format), None
+        token = (st.st_size, st.st_mtime_ns, st.st_ino)
+        hit = self._sniffed.get(path)
+        if hit is None or hit[0] != token:
+            hit = self._sniffed[path] = (
+                token, registry.resolve_reader(path, self.format))
+        return hit[1], st
 
     def iter_chunks(self) -> Iterator[EventFrame]:
         """Raw chunk frames (this handle's plan steps applied, masks
@@ -1008,6 +1035,7 @@ class StreamingTrace:
         clone._steps = tuple(steps)
         clone._pool = self._pool
         clone._units_cache = self._units_cache  # same paths, same plans
+        clone._sniffed = self._sniffed
         clone._ingest = self._ingest  # one report per logical handle
         return clone
 

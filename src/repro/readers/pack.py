@@ -75,8 +75,10 @@ import mmap
 import os
 import struct
 import tempfile
+import threading
 import warnings
 import zlib
+from collections import OrderedDict
 from typing import Any, Dict, Iterator, List, Optional, Set, Tuple
 
 import numpy as np
@@ -162,9 +164,10 @@ def _io(key: str) -> int:
 #: the key because append workloads can grow a pack within one mtime
 #: granule on coarse-mtime filesystems; size alone is not enough once a
 #: finalize rewrites the tail in place.  Failures are never cached:
-#: damage is re-diagnosed on every open.
-_VERIFIED_CLEAN: Dict[tuple, set] = {}
-_VERIFIED_CLEAN_MAX = 256
+#: damage is re-diagnosed on every open.  Least recently marked goes
+#: first when full.
+_VERIFIED_CLEAN: "OrderedDict[tuple, set]" = OrderedDict()
+_VERIFIED_CLEAN_MAX = 4096
 
 
 def _verify_key(path: str, st: os.stat_result, n_groups: int = -1) -> tuple:
@@ -173,10 +176,11 @@ def _verify_key(path: str, st: os.stat_result, n_groups: int = -1) -> tuple:
 
 
 def _mark_verified(key: tuple, aspect: str) -> None:
-    if key not in _VERIFIED_CLEAN and \
-            len(_VERIFIED_CLEAN) >= _VERIFIED_CLEAN_MAX:
-        _VERIFIED_CLEAN.clear()
-    _VERIFIED_CLEAN.setdefault(key, set()).add(aspect)
+    with _OPEN_LOCK:
+        _VERIFIED_CLEAN.setdefault(key, set()).add(aspect)
+        _VERIFIED_CLEAN.move_to_end(key)
+        while len(_VERIFIED_CLEAN) > _VERIFIED_CLEAN_MAX:
+            _VERIFIED_CLEAN.popitem(last=False)
 
 
 def io_stats() -> Dict[str, int]:
@@ -194,26 +198,13 @@ def reset_io_stats() -> None:
 
 
 # ---------------------------------------------------------------------------
-# footer access
+# footer access, and the opened state kept across passes
 # ---------------------------------------------------------------------------
 
-_FOOTER_CACHE: Dict[str, Tuple[Tuple[int, int], dict]] = {}
-
-
-def read_footer(path: str) -> dict:
-    """Parse and return the footer of ``path`` (cached per (size, mtime)).
-
-    Raises :class:`TraceReadError` (a ValueError) when the file is not a
-    readable pack, always naming the path and what was wrong.
-    """
-    path = os.fspath(path)
-    st = os.stat(path)
+def _parse_footer(path: str, st: os.stat_result) -> dict:
+    """Parse the footer of ``path``, whose stat is ``st``."""
     if st.st_size == 0:
         raise TraceReadError(path, "empty file (0 bytes) — not a pack")
-    key = (st.st_size, st.st_mtime_ns)
-    hit = _FOOTER_CACHE.get(path)
-    if hit is not None and hit[0] == key:
-        return hit[1]
     with open(path, "rb") as f:
         head = f.read(len(MAGIC))
         if not head.startswith(MAGIC_PREFIX):
@@ -240,27 +231,125 @@ def read_footer(path: str) -> dict:
         raise TraceReadError(path, f"unsupported pack version "
                                    f"{footer.get('version')!r} (this reader "
                                    f"supports 1 and {VERSION})")
-    if len(_FOOTER_CACHE) > 256:
-        _FOOTER_CACHE.clear()
-    _FOOTER_CACHE[path] = (key, footer)
     return footer
 
 
-def is_pack(path: str) -> bool:
+def _map(path: str) -> np.ndarray:
+    """One read-only map of the whole file, as bytes: a plain array over
+    the ``np.memmap`` (which it keeps alive), so that slicing it skips the
+    memmap subclass's Python-level hooks."""
+    return np.memmap(path, dtype=np.uint8, mode="r").view(np.ndarray)
+
+
+class _PackFile:
+    """What opening one pack yields, kept across passes and requests while
+    the file's stat token ``(st_size, st_mtime_ns, st_ino)`` holds: the
+    parsed footer and name table, one read-only map of the whole file, the
+    column and sidecar views cut from it, and whether the sidecar passed
+    its range check.  An open that quarantines chunks, salvages the footer
+    or drops the sidecar forgets the record (:func:`_forget`), so damage
+    is re-diagnosed on every open."""
+
+    def __init__(self, apath: str, st: os.stat_result, footer: dict):
+        self.path = apath
+        self.st = st
+        self.footer = footer
+        self.opened = False  # a clean open has used it: later ones reuse
+        self._names: Optional[np.ndarray] = None
+        self._raw: Optional[np.ndarray] = None
+        self._cols = None
+        self.side: Optional[Dict[str, np.ndarray]] = None  # range-checked
+
+    def names(self) -> np.ndarray:
+        if self._names is None:
+            self._names = _name_table(self.footer)
+        return self._names
+
+    def raw(self) -> np.ndarray:
+        if self._raw is None:
+            self._raw = _map(self.path)
+        return self._raw
+
+    def columns(self):
+        """The whole file's column views (footer chunks, no quarantine)."""
+        if self._cols is None:
+            self._cols = _column_views(self.path, self.footer,
+                                       self.footer["chunks"], self.raw())
+        return self._cols
+
+
+def _open_bound() -> int:
+    """Records kept: a whole shard set of thousands of files, but each
+    record's map holds a file descriptor, so at most half the process's
+    descriptor limit."""
     try:
-        with open(path, "rb") as f:
-            return f.read(len(MAGIC_PREFIX)) == MAGIC_PREFIX
-    except OSError:
-        return False
+        import resource
+        soft = resource.getrlimit(resource.RLIMIT_NOFILE)[0]
+    except (ImportError, OSError, ValueError):
+        return 4096
+    return 4096 if soft < 0 else max(64, min(4096, soft // 2))
+
+
+#: opened packs by absolute path, least recently used first
+_OPEN: "OrderedDict[str, _PackFile]" = OrderedDict()
+_OPEN_MAX = _open_bound()
+_OPEN_LOCK = threading.Lock()
+
+
+def _stat_token(st: os.stat_result) -> tuple:
+    return (st.st_size, st.st_mtime_ns, st.st_ino)
+
+
+def _pack_file(path: str, st: Optional[os.stat_result] = None) -> _PackFile:
+    """The kept record of ``path``: one ``os.stat`` (or ``st``, taken by
+    the caller) when the file is unchanged, a fresh footer parse when its
+    stat token moved.  Raises like :func:`read_footer`."""
+    apath = os.path.abspath(path)
+    if st is None:
+        st = os.stat(path)
+    with _OPEN_LOCK:
+        rec = _OPEN.get(apath)
+        if rec is not None:
+            if _stat_token(rec.st) == _stat_token(st):
+                _OPEN.move_to_end(apath)
+                return rec
+            del _OPEN[apath]
+    rec = _PackFile(apath, st, _parse_footer(path, st))
+    with _OPEN_LOCK:
+        _OPEN[apath] = rec
+        _OPEN.move_to_end(apath)
+        while len(_OPEN) > _OPEN_MAX:
+            _OPEN.popitem(last=False)
+    return rec
+
+
+def _forget(path: str) -> None:
+    """Drop the kept record of ``path`` (rewritten, or found damaged)."""
+    with _OPEN_LOCK:
+        _OPEN.pop(os.path.abspath(path), None)
+
+
+def _kept(rec: _PackFile) -> bool:
+    with _OPEN_LOCK:
+        return _OPEN.get(rec.path) is rec
+
+
+def read_footer(path: str) -> dict:
+    """Parse and return the footer of ``path`` (kept while the file's
+    size, mtime and inode hold).
+
+    Raises :class:`TraceReadError` (a ValueError) when the file is not a
+    readable pack, always naming the path and what was wrong.
+    """
+    return _pack_file(os.fspath(path)).footer
 
 
 def content_id(path: str) -> Optional[str]:
     """The pack's stored content id (SHA-256 over column + sidecar bytes),
-    or None when ``path`` is not a readable pack.  Footer-only read — the
-    plan cache calls this per terminal op."""
+    or None when ``path`` is not a readable pack.  Footer-only read, one
+    ``os.stat`` while the file is unchanged — the plan cache calls this
+    per terminal op."""
     try:
-        if not is_pack(path):
-            return None
         return read_footer(path).get("content_id")
     except (OSError, ValueError):
         return None
@@ -361,6 +450,7 @@ class PackWriter:
                     and os.path.getsize(self.path) > 0:
                 self._resume()
                 return
+            _forget(self.path)  # rewritten in place
             self._out = open(self.path, "wb")
         self._out.write(MAGIC2)
         self._off = len(MAGIC2)
@@ -402,7 +492,7 @@ class PackWriter:
             self._hash.update(self._out.read(ch["nbytes"]))
         self._out.seek(self._off)
         self._out.truncate(self._off)
-        _FOOTER_CACHE.pop(self.path, None)
+        _forget(self.path)
         _LIVE_SCAN.pop(os.path.abspath(self.path), None)
 
     @property
@@ -642,7 +732,7 @@ class PackWriter:
         if self.atomic:
             os.replace(self._tmp, self.path)
         self._finished = True
-        _FOOTER_CACHE.pop(self.path, None)
+        _forget(self.path)
         _LIVE_SCAN.pop(os.path.abspath(self.path), None)
         return self.path
 
@@ -1004,17 +1094,24 @@ def _resolve_live(path: str, upto_rows: Optional[int]
     return footer, chunks
 
 
-def _resolve_chunks(path: str, on_error: str) -> Tuple[dict, List[dict], bool]:
-    """Open policy front door: returns ``(footer, chunks, intact)`` where
-    ``chunks`` are the surviving chunk records rebased to the surviving
-    row space and ``intact`` says whether every original chunk survived
-    (the sidecar is only meaningful then)."""
+def _resolve_chunks(path: str, on_error: str,
+                    st: Optional[os.stat_result] = None
+                    ) -> Tuple[Optional[_PackFile], dict, List[dict], bool]:
+    """Open policy front door: returns ``(record, footer, chunks, intact)``
+    where ``chunks`` are the surviving chunk records rebased to the
+    surviving row space and ``intact`` says whether every original chunk
+    survived (the sidecar is only meaningful then).  ``record`` is the
+    kept :class:`_PackFile` (None for a salvaged footer); a damaged open
+    has already forgotten it.  ``st``: the caller's fresh stat of ``path``,
+    if it took one."""
     check_on_error(on_error, _ON_ERROR_MODES)
     # an empty file is total data loss under every policy — salvage must
     # not dress it up as a successfully-recovered empty trace
-    require_nonempty(path, os.stat(path).st_size, what="pack")
+    if st is None:
+        st = os.stat(path)
+    require_nonempty(path, st.st_size, what="pack")
     try:
-        footer = read_footer(path)
+        rec = _pack_file(path, st)
     except (OSError, ValueError) as e:
         if on_error == "strict":
             raise
@@ -1023,34 +1120,28 @@ def _resolve_chunks(path: str, on_error: str) -> Tuple[dict, List[dict], bool]:
                 path, f"footer unreadable ({e}); on_error='skip_chunk' "
                       f"needs an intact footer — use on_error='salvage'")
         footer = _salvage_footer(path)
-        return footer, _reindex(footer["chunks"]), False
+        return None, footer, _reindex(footer["chunks"]), False
+    footer = rec.footer
     if footer["version"] == 1 or on_error == "strict":
-        return footer, list(footer["chunks"]), True
+        return rec, footer, list(footer["chunks"]), True
     # v2 + verifying mode: CRC every chunk, quarantine failures.  A file
     # that already passed a full sweep is not re-swept until it changes.
-    st = os.stat(path)
-    key = _verify_key(path, st, len(footer["chunks"]))
+    key = _verify_key(path, rec.st, len(footer["chunks"]))
     if "chunks" in _VERIFIED_CLEAN.get(key, ()):
         _io_add("verify_cache_hits")
-        return footer, list(footer["chunks"]), True
-    size = st.st_size
-    good: List[dict] = []
-    bad = 0
-    with open(path, "rb") as f, \
-            mmap.mmap(f.fileno(), 0, access=mmap.ACCESS_READ) as mm:
-        for ch in footer["chunks"]:
-            if _verify_chunk(mm, ch, size):
-                good.append(ch)
-            else:
-                bad += 1
+        return rec, footer, list(footer["chunks"]), True
+    raw = rec.raw()
+    good = [ch for ch in footer["chunks"] if _verify_chunk(raw, ch, raw.size)]
+    bad = len(footer["chunks"]) - len(good)
     if bad:
+        _forget(path)
         _io_add("chunks_quarantined", bad)
         warnings.warn(f"{path}: quarantined {bad} chunk group(s) failing "
                       f"CRC; {len(good)} intact group(s) kept",
-                      RuntimeWarning, stacklevel=3)
-        return footer, _reindex(good), False
+                      RuntimeWarning, stacklevel=4)
+        return rec, footer, _reindex(good), False
     _mark_verified(key, "chunks")
-    return footer, good, True
+    return rec, footer, good, True
 
 
 def verify_pack(path: str) -> dict:
@@ -1122,21 +1213,39 @@ def _shard_procs_pack(path: str) -> Optional[Set[int]]:
         return None
 
 
-def _open_columns_v1(path: str, footer: dict) -> Dict[str, np.ndarray]:
+def _column_views(path: str, footer: dict, chunks: List[dict],
+                  raw: np.ndarray):
+    """Zero-copy column views over ``raw``, the map of ``path``: v1
+    whole-file columns, or v2 chunk groups (``chunks`` rebased)."""
+    if footer["version"] == 1:
+        return _columns_v1(path, footer, raw)
+    return _GroupColumnSource(path, raw, chunks, footer["has_thread"],
+                              footer["has_messages"])
+
+
+def _columns_v1(path: str, footer: dict, raw: np.ndarray
+                ) -> Dict[str, np.ndarray]:
     rows = footer["rows"]
     out = {}
     for c in footer["columns"]:
-        out[c["key"]] = np.memmap(path, dtype=np.dtype(c["dtype"]), mode="r",
-                                  offset=c["offset"], shape=(rows,))
+        dt = np.dtype(c["dtype"])
+        off = c["offset"]
+        if off + rows * dt.itemsize > raw.size:
+            raise TraceReadError(
+                path, f"column {c['key']!r} extends past end of file "
+                      f"(truncated pack?)", locus=f"byte {off}")
+        out[c["key"]] = raw[off:off + rows * dt.itemsize].view(dt)
     return out
 
 
 def _assemble_columns(path: str, chunks: List[dict], rows: int,
-                      has_thread: bool, has_messages: bool
+                      has_thread: bool, has_messages: bool,
+                      raw: Optional[np.ndarray] = None
                       ) -> Dict[str, np.ndarray]:
     """Materialize whole columns from v2 chunk groups: one allocation per
     column, one memcpy per (group, column) slice — still zero-parse.
-    ``chunks`` must be rebased (contiguous lo/hi over ``rows``)."""
+    ``chunks`` must be rebased (contiguous lo/hi over ``rows``); ``raw``
+    is a map of ``path`` already made, else one is made here."""
     out: Dict[str, np.ndarray] = {
         "ts": np.empty(rows, "<i8"), "et": np.empty(rows, "<i1"),
         "name": np.empty(rows, "<i4"), "proc": np.empty(rows, "<i4")}
@@ -1148,7 +1257,8 @@ def _assemble_columns(path: str, chunks: List[dict], rows: int,
         out["tag"] = np.zeros(rows, "<i4")
     if not chunks:
         return out
-    raw = np.memmap(path, dtype=np.uint8, mode="r")
+    if raw is None:
+        raw = _map(path)
     size = raw.shape[0]
     for ch in chunks:
         n = ch["hi"] - ch["lo"]
@@ -1184,10 +1294,10 @@ class _GroupColumn:
 
 
 class _GroupColumnSource:
-    def __init__(self, path: str, chunks: List[dict], has_thread: bool,
-                 has_messages: bool):
+    def __init__(self, path: str, raw: np.ndarray, chunks: List[dict],
+                 has_thread: bool, has_messages: bool):
         self._path = path
-        self._raw = np.memmap(path, dtype=np.uint8, mode="r")
+        self._raw = raw
         self._spans: List[Tuple[int, int, Dict[str, Tuple[int, str, int]]]] \
             = []
         for ch in chunks:
@@ -1238,58 +1348,82 @@ class _GroupColumnSource:
         return np.concatenate(parts).astype(dt, copy=False)
 
 
-def _open_sidecar(path: str, footer: dict, on_error: str = "strict"
+def _drop_sidecar(rec: _PackFile, why: str) -> None:
+    _forget(rec.path)
+    _io_add("sidecars_dropped")
+    warnings.warn(f"{rec.path}: structure sidecar {why}; falling back to "
+                  f"derive_structure", RuntimeWarning, stacklevel=5)
+
+
+def _open_sidecar(rec: _PackFile, on_error: str = "strict"
                   ) -> Optional[Dict[str, np.ndarray]]:
-    """Memmap the structure sidecar; a corrupt/truncated sidecar degrades
-    gracefully (warning + derive-on-demand) instead of failing the open."""
+    """The structure sidecar's views over the record's map; a corrupt or
+    truncated sidecar degrades gracefully (warning + derive-on-demand,
+    the record forgotten) instead of failing the open.  The range check
+    runs once a record, the CRC sweep once a stat token."""
+    footer = rec.footer
     meta = footer.get("sidecar")
     if not meta:
         return None
     rows = footer["rows"]
-    try:
-        side = {c["key"]: np.memmap(path, dtype=np.dtype(c["dtype"]),
-                                    mode="r", offset=c["offset"],
-                                    shape=(rows,))
-                for c in meta}
-    except (OSError, ValueError) as e:
-        _io_add("sidecars_dropped")
-        warnings.warn(f"{path}: structure sidecar unreadable ({e}); falling "
-                      f"back to derive_structure", RuntimeWarning,
-                      stacklevel=3)
-        return None
+    side = rec.side
+    if side is None:
+        raw = rec.raw()
+        side = {}
+        for c in meta:
+            dt = np.dtype(c["dtype"])
+            off, nb = c["offset"], rows * dt.itemsize
+            if off < 0 or off + nb > raw.size:
+                _drop_sidecar(rec, f"unreadable ({c['key']!r} at byte {off} "
+                                   f"extends past end of file)")
+                return None
+            side[c["key"]] = raw[off:off + nb].view(dt)
     if on_error != "strict" and footer.get("sidecar_crc") is not None:
-        key = _verify_key(path, os.stat(path),
-                          len(footer.get("chunks", ())))
+        key = _verify_key(rec.path, rec.st, len(footer.get("chunks", ())))
         if "sidecar" not in _VERIFIED_CLEAN.get(key, ()):
+            raw = rec.raw()
             lo = meta[0]["offset"]
             hi = (meta[-1]["offset"]
                   + rows * np.dtype(meta[-1]["dtype"]).itemsize)
-            with open(path, "rb") as f, \
-                    mmap.mmap(f.fileno(), 0, access=mmap.ACCESS_READ) as mm:
-                ok = hi <= len(mm) and zlib.crc32(mm[lo:hi]) == \
-                    footer["sidecar_crc"]
-            if not ok:
-                _io_add("sidecars_dropped")
-                warnings.warn(f"{path}: structure sidecar fails CRC; "
-                              f"falling back to derive_structure",
-                              RuntimeWarning, stacklevel=3)
+            if not (hi <= raw.size and zlib.crc32(raw[lo:hi])
+                    == footer["sidecar_crc"]):
+                _drop_sidecar(rec, "fails CRC")
                 return None
             _mark_verified(key, "sidecar")
-    # even without a CRC pass (strict mode stays zero-scan over the data
-    # columns), the row-index columns feed fancy-indexing — an out-of-range
-    # value from a damaged sidecar must degrade, not crash
-    for key in ("matching", "parent"):
-        if key in side and rows:
-            idx = np.asarray(side[key], np.int64)
-            if int(idx.max(initial=-1)) >= rows or \
-                    int(idx.min(initial=0)) < -1:
-                _io_add("sidecars_dropped")
-                warnings.warn(
-                    f"{path}: structure sidecar has out-of-range row "
-                    f"indices (corrupt?); falling back to "
-                    f"derive_structure", RuntimeWarning, stacklevel=3)
-                return None
+    if rec.side is None:
+        # even without a CRC pass (strict mode stays zero-scan over the
+        # data columns), the row-index columns feed fancy-indexing — an
+        # out-of-range value from a damaged sidecar must degrade, not crash
+        for key in ("matching", "parent"):
+            if key in side and rows:
+                idx = np.asarray(side[key], np.int64)
+                if int(idx.max(initial=-1)) >= rows or \
+                        int(idx.min(initial=0)) < -1:
+                    _drop_sidecar(rec, "has out-of-range row indices "
+                                       "(corrupt?)")
+                    return None
+        rec.side = side
     return side
+
+
+def _open(path: str, on_error: str, sidecar: bool, live: bool,
+          upto_rows: Optional[int], st: Optional[os.stat_result] = None):
+    """One pass's open of ``path``: ``(record, footer, chunks, intact,
+    side)``.  Counts ``read.opens_reused`` when a kept record from an
+    earlier clean open serves it, ``read.opens_fresh`` otherwise; live
+    and watermark-pinned reads of growing shards keep their own path and
+    count neither."""
+    if live or upto_rows is not None:
+        footer, chunks = _resolve_live(path, upto_rows)
+        return None, footer, chunks, False, None  # no sidecar: derive
+    rec, footer, chunks, intact = _resolve_chunks(path, on_error, st)
+    side = _open_sidecar(rec, on_error) if sidecar and intact else None
+    kept = intact and _kept(rec)
+    tracer.counter("read.opens_reused" if kept and rec.opened
+                   else "read.opens_fresh")
+    if kept:
+        rec.opened = True
+    return rec, footer, chunks, intact, side
 
 
 def _name_table(footer: dict) -> np.ndarray:
@@ -1371,12 +1505,9 @@ def read_pack(path: str, label: Optional[str] = None,
     path = os.fspath(path)
     report = report if report is not None else IngestReport()
     quar0 = _io("chunks_quarantined")
-    if live or upto_rows is not None:
-        footer, chunks = _resolve_live(path, upto_rows)
-        intact = False  # live prefixes carry no sidecar; derive lazily
-    else:
-        footer, chunks, intact = _resolve_chunks(path, on_error)
-    names = _name_table(footer)
+    rec, footer, chunks, intact, side = _open(path, on_error, sidecar, live,
+                                              upto_rows)
+    names = rec.names() if rec is not None else _name_table(footer)
     rows = sum(c["hi"] - c["lo"] for c in chunks)
     report.begin(path)
     q = _io("chunks_quarantined") - quar0
@@ -1385,15 +1516,14 @@ def read_pack(path: str, label: Optional[str] = None,
                     "chunk groups quarantined (CRC/structure fault)")
     report.add_rows(path, rows)
     if footer["version"] == 1:
-        cols = _open_columns_v1(path, footer)
+        cols = rec.columns()
     else:
         cols = _assemble_columns(path, chunks, rows, footer["has_thread"],
-                                 footer["has_messages"])
+                                 footer["has_messages"],
+                                 rec.raw() if rows and rec else None)
     ev = _frame_slice(cols, names, 0, rows, uniform=False)
     t = Trace(ev, label=label or path)
     t._ingest = report
-    side = (_open_sidecar(path, footer, on_error)
-            if sidecar and intact else None)
     if side is not None:
         matching = np.asarray(side["matching"], np.int64)
         ev[MATCH] = matching
@@ -1455,7 +1585,8 @@ def iter_chunks_pack(path: str, chunk_rows: int,
                      sidecar: bool = True,
                      on_error: str = "strict",
                      report=None, live: bool = False,
-                     upto_rows: Optional[int] = None
+                     upto_rows: Optional[int] = None,
+                     st: Optional[os.stat_result] = None
                      ) -> Iterator[EventFrame]:
     """Stream a pack in EventFrame chunks of at most ``chunk_rows`` rows.
 
@@ -1472,19 +1603,20 @@ def iter_chunks_pack(path: str, chunk_rows: int,
     and ``"salvage"`` streams a footer-less pack from its trailer scan.
     ``live`` / ``upto_rows`` follow :func:`read_pack`: stream the
     committed prefix of a still-growing shard, pinned to a watermark.
+    ``st`` is an ``os.stat(path)`` the caller has just taken for this
+    pass (a streaming handle's format check): the open then takes none of
+    its own.
     """
-    # opening the file for this pass: footer, column and sidecar maps,
-    # pushdown over the footer index
+    # opening the file for this pass (footer, column and sidecar views,
+    # kept from an earlier pass while the file is unchanged), pushdown
+    # over the footer index
     with tracer.span("read.open"):
         path = os.fspath(path)
         reporting = report is not None and row_range is None
         quar0 = _io("chunks_quarantined") if reporting else 0
-        if live or upto_rows is not None:
-            footer, fchunks = _resolve_live(path, upto_rows)
-            intact = False
-        else:
-            footer, fchunks, intact = _resolve_chunks(path, on_error)
-        names = _name_table(footer)
+        rec, footer, fchunks, intact, side = _open(path, on_error, sidecar,
+                                                   live, upto_rows, st)
+        names = rec.names() if rec is not None else _name_table(footer)
         total = sum(c["hi"] - c["lo"] for c in fchunks)
         if reporting:
             report.begin(path)
@@ -1493,15 +1625,13 @@ def iter_chunks_pack(path: str, chunk_rows: int,
                 report.skip(path, q, "",
                             "chunk groups quarantined (CRC/structure fault)")
             report.add_rows(path, total)
-        if footer["version"] == 1:
-            cols = _open_columns_v1(path, footer)
+        if intact:
+            cols = rec.columns()
         elif fchunks:
-            cols = _GroupColumnSource(path, fchunks, footer["has_thread"],
-                                      footer["has_messages"])
+            cols = _column_views(path, footer, fchunks,
+                                 rec.raw() if rec else _map(path))
         else:
             cols = {}  # nothing committed yet — no bytes to map
-        side = (_open_sidecar(path, footer, on_error)
-                if sidecar and intact else None)
         r_lo, r_hi = (0, total) if row_range is None else (
             int(row_range[0]), int(row_range[1]))
         # pushdown at footer-chunk granularity, then coalesce surviving runs

@@ -16,14 +16,14 @@ before parsing* — predicate pushdown into the reader.
 from __future__ import annotations
 
 import os
-from typing import List, Optional, Sequence, Set, Tuple
+from typing import Callable, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
 from ..core.constants import (DERIVED_COLUMNS, ENTER, ET, INSTANT, LEAVE,
                               NAME, PROC, TS)
 from ..core.frame import Categorical, EventFrame, concat
-from ..core.registry import resolve_reader
+from ..core.registry import ReaderSpec, resolve_reader
 from ..core.trace import Trace
 # spawn-safety rules and pool construction live in repro.parallel_util so
 # every parallel driver (this reader, TraceSet preparation, the plan
@@ -53,13 +53,16 @@ def _read_one(args) -> EventFrame:
 
 def select_shards(paths: Sequence[str], kind: str = "auto",
                   procs: Optional[Set[int]] = None,
-                  proc_bounds: Optional[Tuple[float, float]] = None
+                  proc_bounds: Optional[Tuple[float, float]] = None,
+                  resolve: Optional[Callable[[str], ReaderSpec]] = None
                   ) -> List[str]:
     """Shards that can contribute events under the given process restriction.
 
     A shard is kept when its reader provides no ``shard_procs`` hint (unknown
     contents are never skipped) or when any hinted process id satisfies both
-    the explicit set and the [lo, hi] bounds.
+    the explicit set and the [lo, hi] bounds.  ``resolve`` maps a path to
+    its reader (a handle's remembered sniff); ``resolve_reader(p, kind)``
+    by default.
     """
     paths = list(paths)
     if procs is None and proc_bounds is None:
@@ -67,7 +70,7 @@ def select_shards(paths: Sequence[str], kind: str = "auto",
     _ensure_registered()
     keep: List[str] = []
     for p in paths:
-        spec = resolve_reader(p, kind)
+        spec = resolve(p) if resolve is not None else resolve_reader(p, kind)
         hint = spec.shard_procs(p) if spec.shard_procs else None
         if hint is None:
             keep.append(p)

@@ -336,6 +336,29 @@ def test_served_scan_spans_grow_with_chunks_not_records(pack_paths):
             2 * chunks + 2, name
 
 
+def test_served_scan_reuses_every_shard_open(pack_paths):
+    """After the first request a pooled handle's pass reuses each pack
+    shard's kept open: one ``read.opens_reused`` a shard, no fresh one."""
+    plancache.clear()
+    for p in pack_paths:
+        pack._forget(p)
+
+    def work(port):
+        with ServiceClient("127.0.0.1", port) as c:
+            q = c.open(pack_paths, streaming=True).query()
+            s0 = c.stats()["telemetry"]
+            q.run("flat_profile", backend="pallas", cache=False)
+            s1 = c.stats()["telemetry"]
+            q.run("comm_matrix", output="size", backend="pallas",
+                  cache=False)
+            return s0, s1, c.stats()["telemetry"]
+
+    s0, s1, s2 = _serve(work)
+    assert _delta(s0, s1, "counters", "read.opens_fresh") == len(pack_paths)
+    assert _delta(s1, s2, "counters", "read.opens_reused") == len(pack_paths)
+    assert _delta(s1, s2, "counters", "read.opens_fresh") == 0
+
+
 def test_elapsed_ms_is_the_execute_span(pack_paths):
     plancache.clear()
 
