@@ -23,7 +23,8 @@ kernel calls so merely importing the core never pulls the accelerator
 stack.  The sort is timed as the ``accel.sort`` span, and each adapter
 call (operand conversion, upload, dispatch, wait and fetch) as an
 ``accel.kernel`` span; the ``accel.h2d_bytes`` counter adds up the
-bytes its host operands take on the device.
+bytes its host operands take on the device, and ``accel.pair_tiles`` the
+output tiles each ``pair_sum`` call visited.
 """
 
 from __future__ import annotations
@@ -49,14 +50,15 @@ def block_size(n: int, rows: int) -> int:
     """Deterministic record-block size (lanes) for the record kernels.
 
     ``rows`` is the number of one-hot rows a block builds per record (the
-    output widths, e.g. ``n_seg`` or ``n_a + n_b``).  The block is 256
-    lanes for small inputs, doubled until the sequential grid stays under
-    ~512 steps, and capped so that ``rows × BE`` (rows padded to the
-    8-sublane tile) stays within one 2 MiB f32 tile — never below the
-    128-lane width, never above 32768.  A pure function of the record count
-    and the output widths: every execution path holding the same record
-    multiset for the same op picks the same partitioning, which keeps f32
-    block sums — and therefore result digests — path-identical."""
+    output widths, e.g. ``n_seg``, or ``TA + TB`` of one pair_sum tile).
+    The block is 256 lanes for small inputs, doubled until the sequential
+    grid stays under ~512 steps, and capped so that ``rows × BE`` (rows
+    padded to the 8-sublane tile) stays within one 2 MiB f32 tile — never
+    below the 128-lane width, never above 32768.  A pure function of the
+    record count and the output widths: every execution path holding the
+    same record multiset for the same op picks the same partitioning,
+    which keeps f32 block sums — and therefore result digests —
+    path-identical."""
     rows8 = -(-max(int(rows), 1) // 8) * 8
     cap = max(_TILE_ELEMS // rows8, 128)
     cap = min(1 << (cap.bit_length() - 1), 32768)
@@ -130,19 +132,25 @@ def seg_sum(code: np.ndarray, values: np.ndarray, n_seg: int) -> np.ndarray:
 def pair_sum(a: np.ndarray, b: np.ndarray, w: np.ndarray, n_a: int,
              n_b: int) -> np.ndarray:
     """Weighted 2-D scatter-add on the accelerator: a, b [N] (<0 ignored),
-    w [N] → float64 [n_a, n_b]."""
+    w [N] → float64 [n_a, n_b].  The record block is sized by the rows of
+    one output tile (``repro.kernels.pair_sum.tile_shape``), and the
+    ``accel.pair_tiles`` counter adds the tiles the call visited."""
     if n_a <= 0 or n_b <= 0:
         return np.zeros((max(n_a, 0), max(n_b, 0)))
     import jax.numpy as jnp
 
     from ..kernels.ops import pair_sum_matrix
+    from ..kernels.pair_sum import tile_shape
+    ta, tb = tile_shape(int(n_a), int(n_b))
     with tracer.span("accel.kernel", kernel="pair_sum"):
         ops = (np.asarray(a, np.int32), np.asarray(b, np.int32),
                np.asarray(w, np.float32))
         out = np.asarray(pair_sum_matrix(
             *(jnp.asarray(x) for x in ops), n_a=int(n_a), n_b=int(n_b),
-            be=block_size(len(ops[0]), n_a + n_b)), np.float64)
+            be=block_size(len(ops[0]), ta + tb)), np.float64)
     count_upload(*ops)
+    # every output tile is visited once, with records or not
+    tracer.counter("accel.pair_tiles", -(-n_a // ta) * -(-n_b // tb))
     return out
 
 

@@ -7,7 +7,8 @@ import jax.numpy as jnp
 
 from ..models.attention import reference_attention
 
-__all__ = ["flash_attention_ref", "time_bin_ref", "topk_gating_ref"]
+__all__ = ["flash_attention_ref", "pair_sum_ref", "time_bin_ref",
+           "topk_gating_ref"]
 
 
 def flash_attention_ref(q, k, v, *, causal=True, window=None, prefix_len=0,
@@ -49,6 +50,12 @@ def time_bin_ref(start, end, func, *, n_funcs, n_bins, t0, t1):
     ov = jnp.where((func >= 0)[:, None], ov, 0.0)
     onehot = jax.nn.one_hot(jnp.maximum(func, 0), n_funcs, dtype=jnp.float32)
     return onehot.T @ ov
+
+
+def pair_sum_ref(a, b, w, *, n_a, n_b):
+    ok = (a >= 0) & (a < n_a) & (b >= 0) & (b < n_b)
+    return jnp.zeros((n_a, n_b), jnp.float32).at[
+        jnp.where(ok, a, 0), jnp.where(ok, b, 0)].add(jnp.where(ok, w, 0.0))
 
 
 def topk_gating_ref(logits, k):

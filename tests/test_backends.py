@@ -232,6 +232,46 @@ def test_digest_identical_across_paths(path_files, op, backend):
     assert result_digest(par) == d0, f"{op}/{backend}: parallel"
 
 
+@pytest.fixture(scope="module")
+def wide_files(tmp_path_factory):
+    """600 ranks, so the 600 x 600 comm matrix spans 2 x 2 output tiles:
+    each rank sends to its next and to the rank 300 on (the corner tiles),
+    the last rank to no one past the end."""
+    d = tmp_path_factory.mktemp("wide")
+    tb = TraceBuilder()
+    n = 600
+    for p in range(n):
+        t = tb.call(float(p % 7), 3.0, "compute", p)
+        for dst in (p + 1, p + 300):
+            if dst < n:
+                t = tb.send(t, 2.0, p, dst, 1000.0 + 37 * p + dst)
+    tr = tb.trace()
+    pack, jsonl = str(d / "w.pack"), str(d / "w.jsonl")
+    write_pack(tr, pack)
+    write_jsonl(tr, jsonl)
+    return pack, jsonl
+
+
+def test_tiled_comm_matrix_digest_identical_across_paths(wide_files):
+    """The same contract when pair_sum's output spans several tiles: the
+    bucketing by tile is a stable function of the canonical order."""
+    pack, jsonl = wide_files
+    kw = {"backend": "pallas"}
+    eager = Trace.open(pack).query().run("comm_matrix", cache=False, **kw)
+    stream = (Trace.open(pack, streaming=True, chunk_rows=301)
+              .query().run("comm_matrix", cache=False, **kw))
+    spec = registry.get_op("comm_matrix")
+    par = execute_parallel(
+        StreamingTrace(jsonl, chunk_rows=257, processes=2), (), spec,
+        (), kw, spec.streaming(**kw), n_units=4, use_pool=False)
+    want = Trace.open(jsonl).comm_matrix()
+    assert np.asarray(eager).shape == (600, 600)
+    np.testing.assert_array_equal(np.asarray(eager), np.asarray(want))
+    d0 = result_digest(eager)
+    assert result_digest(stream) == d0, "streaming"
+    assert result_digest(par) == d0, "parallel"
+
+
 def test_streaming_time_profile_pallas_no_longer_raises(path_files):
     """Regression: streaming time_profile used to hard-raise for any
     non-numpy backend instead of consulting the backend table."""

@@ -9,6 +9,7 @@ import pytest
 from repro.kernels import ref
 from repro.kernels.ops import (flash_attention_gqa, router_topk,
                                time_profile_matrix)
+from repro.kernels.pair_sum import pair_sum, tile_shape
 from repro.models.attention import chunked_attention
 
 # full-matrix jax suites: minutes, not seconds — slow tier only
@@ -66,6 +67,56 @@ def test_time_bin_kernel(N, F, NB):
     # conservation: total binned time == total clipped durations
     assert float(np.asarray(out).sum()) == pytest.approx(
         float(np.asarray(want).sum()))
+
+
+def _records(rng, n, n_a, n_b, integer):
+    """Seeded records over a [n_a, n_b] output, a few ids out of range
+    (ignored); rows from 2/3 of n_a on get none, so some tiles are empty."""
+    a = rng.integers(-1, 2 * n_a // 3, n).astype(np.int32)
+    b = rng.integers(-1, n_b + 2, n).astype(np.int32)
+    w = (rng.integers(1, 1 << 12, n) if integer
+         else rng.standard_normal(n) * 1e3).astype(np.float32)
+    return a, b, w
+
+
+def _numpy_pair_sum(a, b, w, n_a, n_b):
+    ok = (a >= 0) & (a < n_a) & (b >= 0) & (b < n_b)
+    out = np.zeros((n_a, n_b))
+    np.add.at(out, (a[ok], b[ok]), w[ok].astype(np.float64))
+    return out
+
+
+# (n_a, n_b, tile, records, block): 300x200 cut into uneven tiles, the
+# whole output as one tile, and a tiled output with no or few records
+@pytest.mark.parametrize("n_a,n_b,tile,n,be", [
+    (300, 200, (64, 128), 1500, 128), (300, 200, (128, 128), 700, 256),
+    (300, 200, None, 1500, 256), (300, 200, (64, 128), 0, 128),
+    (300, 200, (64, 128), 3, 128)])
+@pytest.mark.parametrize("integer", [True, False], ids=["int", "float"])
+def test_pair_sum_tiled_kernel(n_a, n_b, tile, n, be, integer):
+    rng = np.random.default_rng([n_a, n_b, n, be])
+    a, b, w = _records(rng, n, n_a, n_b, integer)
+    out = np.asarray(jax.jit(lambda a, b, w: pair_sum(
+        a, b, w, n_a=n_a, n_b=n_b, be=be, tile=tile))(a, b, w))
+    assert out.shape == (n_a, n_b)
+    want = _numpy_pair_sum(a, b, w, n_a, n_b)
+    if integer:
+        # integer sums below 2^24 are exact in f32, in any order
+        np.testing.assert_array_equal(out, want)
+        np.testing.assert_array_equal(out, np.asarray(ref.pair_sum_ref(
+            a, b, w, n_a=n_a, n_b=n_b)))
+    else:
+        # one-hot products are exact at HIGHEST; a cell sums a few records
+        # in f32, so it is within a few f32 roundings of its magnitude
+        np.testing.assert_allclose(out, want, rtol=0,
+                                   atol=4e-7 * np.abs(want).max())
+
+
+def test_pair_sum_tile_shape():
+    assert tile_shape(256, 256) == (256, 256)        # one tile, as before
+    assert tile_shape(18, 4096) == (18, 4096)
+    assert tile_shape(4096, 4096) == (512, 512)
+    assert tile_shape(18, 100_000) == (18, 512)      # the narrow side whole
 
 
 @pytest.mark.parametrize("T,E,k", [(64, 8, 2), (777, 64, 4), (32, 128, 8)])

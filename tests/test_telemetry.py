@@ -218,6 +218,30 @@ def test_h2d_bytes_count_the_uploaded_operands(kernel, args, per_record):
     assert _delta(s0, s1, "spans", "accel.kernel", "count") == 1
 
 
+@pytest.mark.parametrize("n_a,n_b,tiles", [
+    # 4 x 5 fits one tile
+    (4, 5, 1),
+    # 600 x 700 is 2 x 2 uneven tiles of at most 512 x 512; the records
+    # fill several record blocks of tile (0, 0), the other three tiles are
+    # visited once, empty
+    (600, 700, 4),
+])
+def test_pair_tiles_count_each_tile_once(n_a, n_b, tiles):
+    from repro.core import accel
+    n = 3000
+    rng = np.random.default_rng(7)
+    a = rng.integers(0, min(n_a, 512), n)
+    b = rng.integers(0, min(n_b, 512), n)
+    w = rng.integers(1, 100, n).astype(float)
+    s0 = tracer.snapshot()
+    out = accel.pair_sum(a, b, w, n_a, n_b)
+    s1 = tracer.snapshot()
+    assert _delta(s0, s1, "counters", "accel.pair_tiles") == tiles
+    want = np.zeros((n_a, n_b))
+    np.add.at(want, (a, b), w)
+    np.testing.assert_array_equal(out, want)
+
+
 # ---------------------------------------------------------------------------
 # the recorder and the pack reader's io view
 # ---------------------------------------------------------------------------
