@@ -302,30 +302,37 @@ def _steps_token(steps) -> tuple:
     return tuple(out)
 
 
-def _stat_token(path: str) -> tuple:
+def _stat_token(path: str, st=None) -> tuple:
+    """Identity of one file from one ``os.stat`` (``st``, when the caller
+    has taken it)."""
     import os
-    st = os.stat(path)
+    if st is None:
+        st = os.stat(path)
     # pack files carry a stored content id (SHA-256 over the column +
     # sidecar bytes): keying by it instead of (size, mtime, inode) means
     # copies and faithful rewrites of a pack share one cache entry, and a
     # re-pack with different content can never produce a stale hit
     from ..readers.pack import content_id
-    cid = content_id(path)
+    cid = content_id(path, st)
     if cid is not None:
         return ("pipitpack", cid)
     return (path, st.st_size, st.st_mtime_ns, st.st_ino)
 
 
 def _paths_token(paths) -> tuple:
+    """Identity of ``paths``, one ``os.stat`` a path: a directory's files
+    in walk order.  Raises ``OSError`` for a path that cannot be stat'ed."""
     import os
+    import stat
     out = []
     for p in paths:
-        if os.path.isdir(p):
+        st = os.stat(p)
+        if stat.S_ISDIR(st.st_mode):
             for root, _dirs, files in sorted(os.walk(p)):
                 out.extend(_stat_token(os.path.join(root, f))
                            for f in sorted(files))
         else:
-            out.append(_stat_token(p))
+            out.append(_stat_token(p, st))
     return tuple(out)
 
 

@@ -344,13 +344,15 @@ def read_footer(path: str) -> dict:
     return _pack_file(os.fspath(path)).footer
 
 
-def content_id(path: str) -> Optional[str]:
+def content_id(path: str,
+               st: Optional[os.stat_result] = None) -> Optional[str]:
     """The pack's stored content id (SHA-256 over column + sidecar bytes),
     or None when ``path`` is not a readable pack.  Footer-only read, one
-    ``os.stat`` while the file is unchanged — the plan cache calls this
-    per terminal op."""
+    ``os.stat`` while the file is unchanged, none when the caller hands
+    its own ``st`` — the plan cache and the service's identity call this
+    per request."""
     try:
-        return read_footer(path).get("content_id")
+        return _pack_file(os.fspath(path), st).footer.get("content_id")
     except (OSError, ValueError):
         return None
 

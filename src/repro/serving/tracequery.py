@@ -13,10 +13,11 @@ rather than just remote:
 
 * **handle pool** — handles are keyed by *content identity* (the pack
   content id where available, ``(path, size, mtime, inode)`` otherwise)
-  plus open parameters, LRU-bounded, and revalidated per request: rewrite
-  a pack on disk and the next query transparently reopens it.  Every
-  session over the same pack shares one mmap and one set of structure
-  sidecars.
+  plus open parameters, LRU-bounded, and revalidated per request against
+  the identity the request was keyed by (taken once, one ``os.stat`` a
+  file): rewrite a pack on disk and the next query transparently reopens
+  it.  Every session over the same pack shares one mmap and one set of
+  structure sidecars.
 * **single-flight coalescing** — identical in-flight plans (same source
   identity, steps, op, arguments) are executed **once**; concurrent
   duplicates await the same future.  The key is the plan-cache digest of
@@ -95,7 +96,7 @@ class _Handle:
         self.key = key
         self.kind = kind    # "trace" | "stream" | "set" | "live" | "liveset"
         self.obj = obj
-        self.ident = ident          # _paths_token at open time
+        self.ident = ident          # _paths_token of the last request
         self.lock = threading.Lock()
         self.opened_at = time.time()
         self.uses = 0
@@ -171,7 +172,8 @@ class HandlePool:
     identity.
 
     ``get()`` revalidates the stored identity (pack content id / stat
-    token) on every call — a handle whose backing files changed on disk
+    token) on every call against the identity the caller took for this
+    request, or takes one — a handle whose backing files changed on disk
     is silently reopened, so long-lived services never serve stale mmaps.
     Opens run under the pool lock (they mutate the LRU); callers should
     invoke ``get()`` off the event loop for sources with slow opens.
@@ -192,7 +194,10 @@ class HandlePool:
         self.breaker_fastfails = 0
 
     def _ident(self, paths: List[str]) -> tuple:
+        """The sources' content identity (one ``os.stat`` a file); raises
+        ``OSError`` when a path cannot be stat'ed."""
         from ..core.plancache import _paths_token
+        tracer.counter("service.identities")
         return _paths_token(paths)
 
     def _open(self, spec: dict):
@@ -236,8 +241,10 @@ class HandlePool:
                 f"`python tools/pack.py --verify {p}` and recover with "
                 f"`--repair`, or reopen with on_error=\"salvage\"")
 
-    def get(self, spec: dict) -> _Handle:
-        """The live handle for ``spec`` (opening or reopening as needed).
+    def get(self, spec: dict, ident: Optional[tuple] = None) -> _Handle:
+        """The live handle for ``spec`` (opening or reopening as needed),
+        revalidated against ``ident``, the sources' identity the caller
+        took for this request (taken here when None).
 
         Repeatedly-failing opens trip a per-spec circuit breaker: after
         ``breaker_threshold`` consecutive failures, requests fast-fail
@@ -246,12 +253,13 @@ class HandlePool:
         on a source that cannot open.  One probe is admitted when the
         cooldown lapses; a successful open resets the breaker."""
         with tracer.span("service.handle"):
-            return self._get(spec)
+            return self._get(spec, ident)
 
-    def _get(self, spec: dict) -> _Handle:
+    def _get(self, spec: dict, ident: Optional[tuple]) -> _Handle:
         key = hashlib.sha256(canonical_json(spec).encode()).hexdigest()
         try:
-            ident = self._ident(spec["paths"])
+            if ident is None:
+                ident = self._ident(spec["paths"])
         except OSError as e:
             if spec.get("mode") in ("live", "liveset"):
                 # a live shard that hasn't appeared yet reads as empty —
@@ -463,16 +471,15 @@ class TraceService:
         return open_spec, op, spec, steps, args, kwargs, cache_flag, \
             lane, digest_only
 
-    def _wire_key(self, open_spec: dict, steps, op: str, payload: dict,
-                  digest_only: bool) -> Optional[str]:
+    @staticmethod
+    def _wire_key(open_spec: dict, ident: Optional[tuple], steps, op: str,
+                  payload: dict, digest_only: bool) -> Optional[str]:
         """Single-flight + service-cache key: a digest of the request plus
-        the *content identity* of its sources.  None when the sources
-        cannot be identified (key construction already raised 404 in
-        ``handles.get`` for missing files; this is only for exotic
-        failures) — such requests execute uncoalesced and uncached."""
-        try:
-            ident = self.handles._ident(open_spec["paths"])
-        except OSError:
+        ``ident``, the *content identity* of its sources.  None when the
+        sources could not be identified (``ident`` None: ``handles.get``
+        then takes the identity again and answers 404 for missing files)
+        — such requests execute uncoalesced and uncached."""
+        if ident is None:
             return None
         body = canonical_json({"open": open_spec, "ident": repr(ident),
                                "steps": steps, "op": op,
@@ -551,7 +558,14 @@ class TraceService:
         else:
             deadline = self.default_deadline
         self.counters[lane] += 1
-        key = self._wire_key(open_spec, steps, op, payload, digest_only)
+        # the sources' identity, taken once: it keys the request and
+        # revalidates the pooled handle
+        try:
+            ident = self.handles._ident(open_spec["paths"])
+        except OSError:
+            ident = None
+        key = self._wire_key(open_spec, ident, steps, op, payload,
+                             digest_only)
 
         # 1. shared plan cache (service layer: keyed by content identity)
         if key is not None and cache_flag is not False:
@@ -641,7 +655,8 @@ class TraceService:
                                      cache_flag, digest_only)
 
         try:
-            handle = await _bounded(lambda: self.handles.get(open_spec))
+            handle = await _bounded(
+                lambda: self.handles.get(open_spec, ident=ident))
             result = await _bounded(lambda: _exec(handle))
             if key is not None and cache_flag is not False:
                 plancache.store(key, result, tenant=tenant)

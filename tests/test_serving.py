@@ -409,6 +409,19 @@ def test_handle_reopened_when_pack_rewritten(tmp_path, fresh_cache):
     assert second["digest"] == result_digest(want)
 
 
+def test_live_open_of_missing_shard_is_pending_not_404(tmp_path,
+                                                      fresh_cache):
+    """A live shard that has not appeared yet reads as empty: the pool
+    keys it by the live-pending identity instead of answering 404."""
+    p = str(tmp_path / "rank_0.pack")
+    service = TraceService()
+    out = run(service.live({"open": {"path": p, "mode": "live"},
+                            "op": "flat_profile", "tenant": "t"}))
+    assert out["ok"] and out["watermark"]["rows"] == 0
+    (h,) = service.handles._handles.values()
+    assert h.kind == "live" and h.ident == ("live-pending", p)
+
+
 def test_handle_pool_lru_bound(pack_paths, fresh_cache):
     async def main():
         service = TraceService(max_handles=2)

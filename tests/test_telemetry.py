@@ -383,6 +383,46 @@ def test_served_scan_reuses_every_shard_open(pack_paths):
     assert _delta(s1, s2, "counters", "read.opens_fresh") == 0
 
 
+@pytest.mark.parametrize("first", [True, False],
+                         ids=["opening", "pooled"])
+def test_served_request_takes_the_sources_identity_once(pack_paths,
+                                                        monkeypatch, first):
+    """A served request computes its sources' identity once, with one
+    ``os.stat`` a shard, for its key and its pooled handle alike; the
+    pass adds its own revalidation, at most two stats a shard."""
+    plancache.clear()
+    calls = {"paths_token": 0, "stat": dict.fromkeys(pack_paths, 0)}
+    real_token, real_stat = plancache._paths_token, os.stat
+
+    def token(paths):
+        calls["paths_token"] += 1
+        return real_token(paths)
+
+    def stat(path, *a, **kw):
+        if path in calls["stat"]:
+            calls["stat"][path] += 1
+        return real_stat(path, *a, **kw)
+
+    def work(port):
+        with ServiceClient("127.0.0.1", port) as c:
+            q = c.open(pack_paths, streaming=True).query()
+            if not first:
+                q.run("flat_profile", backend="pallas", cache=False)
+            s0 = c.stats()["telemetry"]
+            monkeypatch.setattr(plancache, "_paths_token", token)
+            monkeypatch.setattr(os, "stat", stat)
+            try:
+                q.run("flat_profile", backend="pallas", cache=False)
+            finally:
+                monkeypatch.undo()
+            return s0, c.stats()["telemetry"]
+
+    s0, s1 = _serve(work)
+    assert _delta(s0, s1, "counters", "service.identities") == 1
+    assert calls["paths_token"] == 1
+    assert all(1 <= n <= 3 for n in calls["stat"].values()), calls["stat"]
+
+
 def test_elapsed_ms_is_the_execute_span(pack_paths):
     plancache.clear()
 
