@@ -1,5 +1,5 @@
 """NumPy-facing adapters over the Pallas reduction kernels, plus the
-canonical record ordering every accelerator backend shares.
+record set and canonical record ordering every accelerator backend shares.
 
 The op backends registered as ``backend="pallas"`` (flat_profile,
 comm_matrix, message_histogram, load_imbalance, stragglers, time_profile)
@@ -12,11 +12,12 @@ on every path) therefore hinges on one rule:
     **every path sorts its records into the same canonical order and
     invokes the kernel exactly once, at finalize.**
 
-:func:`canonical_order` is that order.  Its keys are path-independent:
-timestamps, process ids, *alphabetical* name positions (never raw category
-or interner codes, which differ between the eager code space and the
-streaming first-seen code space), and the record's own value as the final
-tiebreak.  See docs/kernels.md for the full precision contract.
+Every path holds its records in a :class:`Records`, whose
+:meth:`Records.ordered` is the one caller of :func:`canonical_order`.
+That order's keys are path-independent: timestamps, process ids,
+*alphabetical* name positions (never raw category or interner codes,
+which differ between the eager code space and the streaming first-seen
+code space), and the record's own value as the final tiebreak.  See docs/kernels.md for the full precision contract.
 
 This module is numpy-in / numpy-out — jax is imported lazily inside the
 kernel calls so merely importing the core never pulls the accelerator
@@ -29,14 +30,16 @@ output tiles each ``pair_sum`` call visited.
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import List, Optional, Tuple
 
 import numpy as np
 
+from .constants import ENTER, ET, MATCH, NAME, PROC, TS
 from ..runtime import tracer
 
-__all__ = ["canonical_order", "alpha_positions", "block_size", "seg_sum",
-           "pair_sum", "hist_counts", "count_upload"]
+__all__ = ["Records", "matched_calls", "canonical_order", "alpha_positions",
+           "block_size", "seg_sum", "pair_sum", "hist_counts",
+           "count_upload"]
 
 
 # One-hot tile bound, in f32 elements: a block builds [rows, BE] one-hot and
@@ -92,6 +95,74 @@ def canonical_order(start, end, proc, code, value) -> np.ndarray:
                            np.asarray(proc, np.int64),
                            np.asarray(end, np.float64),
                            np.asarray(start, np.float64)))
+
+
+_COLUMNS = ("start", "end", "proc", "code", "value")
+
+
+class Records:
+    """One accelerated op's records, gathered in parts: a call is (enter
+    ts, leave ts, process, name code, metric), a send (ts, ts, sender,
+    receiver, bytes).  ``value`` is ``[N]``, or ``[N, width]`` for several
+    metrics; ``names`` says whether ``code`` is a name code, which a
+    cross-worker merge remaps.  Plain arrays only, so it pickles."""
+
+    def __init__(self, names: bool = True, width: Optional[int] = None):
+        self.names = names
+        self.width = width
+        self._parts: List[tuple] = []
+
+    def add(self, start, end, proc, code, value) -> None:
+        """Buffer one part's columns as given, uncopied."""
+        self._parts.append((start, end, proc, code, value))
+
+    def merge(self, other: "Records", code_map: np.ndarray) -> None:
+        """Take a worker's parts, name codes mapped through ``code_map``."""
+        self._parts += [(s, e, p, code_map[c] if self.names else c, v)
+                        for s, e, p, c, v in other._parts]
+
+    def columns(self) -> tuple:
+        """The five columns concatenated across parts, kept as one part."""
+        if len(self._parts) != 1:
+            if self._parts:
+                cols = tuple(np.concatenate(c) for c in zip(*self._parts))
+            else:
+                idx, ts = np.zeros(0, np.int64), np.zeros(0)
+                cols = (ts, ts, idx, idx, np.zeros(
+                    (0, self.width) if self.width is not None else 0))
+            self._parts = [cols]
+        return self._parts[0]
+
+    def ordered(self, *which: str, inv: Optional[np.ndarray] = None):
+        """The ``which`` columns in :func:`canonical_order`, each code first
+        replaced by ``inv[code]`` (for name codes, the alphabetical
+        positions of :func:`alpha_positions`)."""
+        cols = dict(zip(_COLUMNS, self.columns()))
+        if inv is not None:
+            cols["code"] = inv[cols["code"]]
+        value = cols["value"]
+        o = canonical_order(cols["start"], cols["end"], cols["proc"],
+                            cols["code"],
+                            value if value.ndim == 1 else value[:, 0])
+        return tuple(cols[k][o] for k in which)
+
+
+def matched_calls(ev, metrics, keep: Optional[np.ndarray] = None
+                  ) -> Records:
+    """The matched calls (Enter rows with a Leave, and ``keep``) of an
+    event frame, NaN-free ``value`` ``[N]`` for one metric name or
+    ``[N, K]`` for a sequence of them."""
+    match = np.asarray(ev.column(MATCH), np.int64)
+    sel = ev.cat(ET).mask_eq(ENTER) & (match >= 0)
+    sel = np.nonzero(sel if keep is None else sel & keep)[0]
+    ts = np.asarray(ev[TS], np.float64)
+    one = isinstance(metrics, str)
+    vals = [np.nan_to_num(np.asarray(ev.column(m), np.float64)[sel])
+            for m in ([metrics] if one else metrics)]
+    recs = Records(width=None if one else len(vals))
+    recs.add(ts[sel], ts[match[sel]], np.asarray(ev[PROC], np.int64)[sel],
+             ev.codes(NAME)[sel], vals[0] if one else np.stack(vals, axis=1))
+    return recs
 
 
 def count_upload(*operands) -> None:

@@ -49,7 +49,7 @@ from __future__ import annotations
 
 import inspect
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -60,7 +60,8 @@ from . import accel
 from .frame import EventFrame
 from .registry import (get_backend, register_backend, register_op,
                        register_streaming)
-from .streaming import StreamAgg, StreamingUnsupported, grow_to
+from .streaming import (StreamAgg, StreamingUnsupported, grow_to,
+                        stream_backend)
 
 __all__ = ["DetectorSpec", "register_detector", "get_detector",
            "list_detectors", "Findings", "FINDINGS_COLUMNS", "is_comm_name",
@@ -507,6 +508,16 @@ def _stragglers_numpy(trace, *, threshold: float = 0.2) -> EventFrame:
     return _straggler_findings(work, t0, t1, nprocs, threshold)
 
 
+def _stragglers_from_records(recs, names, nprocs, t0, t1, threshold
+                             ) -> EventFrame:
+    """The pallas stragglers of every path: the non-comm call records'
+    per-rank busy sums through seg_sum, in canonical order."""
+    _names, _order, inv = accel.alpha_positions(names)
+    proc, exc = recs.ordered("proc", "value", inv=inv)
+    return _straggler_findings(accel.seg_sum(proc, exc, nprocs), t0, t1,
+                               nprocs, threshold)
+
+
 @register_backend("stragglers", "pallas")
 def _stragglers_pallas(trace, *, threshold: float = 0.2) -> EventFrame:
     """Accelerator stragglers: the per-rank busy sum runs through the
@@ -516,19 +527,12 @@ def _stragglers_pallas(trace, *, threshold: float = 0.2) -> EventFrame:
     nprocs = trace.num_processes
     if len(ev) == 0 or nprocs == 0:
         return Findings([])
-    is_enter = ev.cat(ET).mask_eq(ENTER)
-    comm = _comm_cat_mask(ev.cat(NAME).categories)[ev.codes(NAME)]
-    match = np.asarray(ev.column("_matching_event"), np.int64)
-    sel = np.nonzero(is_enter & ~comm & (match >= 0))[0]
-    ts = np.asarray(ev[TS], np.float64)
-    proc = np.asarray(ev[PROC], np.int64)
-    exc = np.nan_to_num(np.asarray(ev.column(EXC), np.float64)[sel])
-    _names, _order, inv = accel.alpha_positions(ev.cat(NAME).categories)
-    acode = inv[ev.codes(NAME)[sel]]
-    o = accel.canonical_order(ts[sel], ts[match[sel]], proc[sel], acode, exc)
-    work = accel.seg_sum(proc[sel][o], exc[o], nprocs)
-    t0, t1 = _rank_bounds(proc, np.asarray(ev[TS], np.int64), nprocs)
-    return _straggler_findings(work, t0, t1, nprocs, threshold)
+    names = ev.cat(NAME).categories
+    comm = _comm_cat_mask(names)[ev.codes(NAME)]
+    t0, t1 = _rank_bounds(np.asarray(ev[PROC], np.int64),
+                          np.asarray(ev[TS], np.int64), nprocs)
+    return _stragglers_from_records(accel.matched_calls(ev, EXC, keep=~comm),
+                                    names, nprocs, t0, t1, threshold)
 
 
 @register_streaming("stragglers")
@@ -540,15 +544,9 @@ class _StragglerAgg(StreamAgg):
     supports_parallel = True
 
     def __init__(self, threshold: float = 0.2, backend: str = "numpy"):
-        get_backend("stragglers", backend)
-        if backend not in ("numpy", "pallas"):
-            raise StreamingUnsupported(
-                f"streaming stragglers supports backends ('numpy', "
-                f"'pallas'); {backend!r} is trace-level — materialize with "
-                f".collect() to use it")
-        self.backend = backend
+        self.backend = stream_backend("stragglers", backend)
         self.threshold = float(threshold)
-        self._recs: List[tuple] = []
+        self._recs = accel.Records()
         self._work = np.zeros(0)
         self._t0 = np.full(0, np.iinfo(np.int64).max, np.int64)
         self._t1 = np.full(0, np.iinfo(np.int64).min, np.int64)
@@ -575,20 +573,16 @@ class _StragglerAgg(StreamAgg):
         if not keep.any():
             return
         if self.backend != "numpy":
-            self._recs.append((calls.name[keep].copy(),
-                               calls.proc[keep].copy(),
-                               calls.start[keep].copy(),
-                               calls.end[keep].copy(),
-                               np.nan_to_num(calls.exc[keep])))
-            return
+            return self._recs.add(calls.start[keep], calls.end[keep],
+                                  calls.proc[keep], calls.name[keep],
+                                  np.nan_to_num(calls.exc[keep]))
         np_ = int(calls.proc[keep].max()) + 1
         self._work = grow_to(self._work, (np_,))
         np.add.at(self._work, calls.proc[keep], calls.exc[keep])
 
     def merge_from(self, other, code_map) -> None:
         if self.backend != "numpy":
-            for name, proc, start, end, exc in other._recs:
-                self._recs.append((code_map[name], proc, start, end, exc))
+            self._recs.merge(other._recs, code_map)
         np_ = max(len(self._work), len(other._work),
                   len(self._t0), len(other._t0))
         self._work = grow_to(self._work, (np_,))
@@ -604,27 +598,16 @@ class _StragglerAgg(StreamAgg):
         nprocs = ctx.num_processes
         if nprocs <= 0:
             return Findings([])
-        if self.backend != "numpy":
-            if self._recs:
-                name = np.concatenate([r[0] for r in self._recs])
-                proc = np.concatenate([r[1] for r in self._recs])
-                start = np.concatenate([r[2] for r in self._recs])
-                end = np.concatenate([r[3] for r in self._recs])
-                exc = np.concatenate([r[4] for r in self._recs])
-            else:
-                name = proc = np.zeros(0, np.int64)
-                start = end = exc = np.zeros(0)
-            _names, _order, inv = accel.alpha_positions(
-                ctx.names.names[: len(ctx.names)])
-            o = accel.canonical_order(start, end, proc, inv[name], exc)
-            work = accel.seg_sum(proc[o], exc[o], nprocs)
-        else:
-            work = np.zeros(nprocs)
-            work[:min(nprocs, len(self._work))] = self._work[:nprocs]
         t0 = np.full(nprocs, np.iinfo(np.int64).max, np.int64)
         t1 = np.full(nprocs, np.iinfo(np.int64).min, np.int64)
         t0[:min(nprocs, len(self._t0))] = self._t0[:nprocs]
         t1[:min(nprocs, len(self._t1))] = self._t1[:nprocs]
+        if self.backend != "numpy":
+            return _stragglers_from_records(
+                self._recs, ctx.names.names[:len(ctx.names)], nprocs, t0, t1,
+                self.threshold)
+        work = np.zeros(nprocs)
+        work[:min(nprocs, len(self._work))] = self._work[:nprocs]
         return _straggler_findings(work, t0, t1, nprocs, self.threshold)
 
 

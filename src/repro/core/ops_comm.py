@@ -7,13 +7,13 @@ from typing import Callable, Optional, Sequence, Tuple
 import numpy as np
 
 from . import accel
-from .constants import (DEFAULT_COMM_PREFIXES, ENTER, ET, INC, LEAVE, MPI_RECV,
-                        MPI_SEND, MSG_SIZE, NAME, PARTNER, PROC, TS)
+from .constants import (DEFAULT_COMM_PREFIXES, ENTER, ET, MPI_SEND, MSG_SIZE,
+                        NAME, PARTNER, PROC, TS)
 from .frame import EventFrame
 from .intervals import merge_intervals
 from .registry import (get_backend, register_backend, register_op,
                        register_streaming)
-from .streaming import StreamAgg, StreamingUnsupported, grow_to
+from .streaming import StreamAgg, grow_to, stream_backend
 
 __all__ = [
     "comm_matrix", "message_histogram", "comm_by_process", "comm_over_time",
@@ -64,17 +64,23 @@ def _comm_matrix_numpy(trace, *, output: str = "size") -> np.ndarray:
     return mat
 
 
-def _wrap_partners(src, dst, n: int, op: str):
-    """Negative partner ids wrap like numpy fancy indexing (``-1`` is the
-    last process); out-of-range ids raise the same IndexError the
-    ``np.add.at`` reference raises instead of silently dropping."""
-    if len(dst) and (int(src.max()) >= n or int(dst.max()) >= n
-                     or int(src.min()) < 0 or int(dst.min()) < -n):
+def _comm_from_records(recs, n: int, op: str) -> np.ndarray:
+    """The pallas comm matrix of every path: the send records through
+    pair_sum, in canonical order.  Negative partner ids wrap like numpy
+    fancy indexing (``-1`` is the last process); out-of-range ids raise
+    the same IndexError the ``np.add.at`` reference raises instead of
+    silently dropping."""
+    _, _, src, dst, _ = recs.columns()
+    if n == 0 or not len(src):
+        return np.zeros((n, n))
+    if (int(src.max()) >= n or int(dst.max()) >= n
+            or int(src.min()) < 0 or int(dst.min()) < -n):
         raise IndexError(
             f"{op}: message endpoints outside the selected trace's "
             f"0..{n - 1} process range (same selection fails on "
             f"backend='numpy' too)")
-    return np.where(dst < 0, dst + n, dst)
+    src, dst, w = recs.ordered("proc", "code", "value", inv=np.arange(n))
+    return accel.pair_sum(src, dst, w, n, n)
 
 
 @register_backend("comm_matrix", "pallas")
@@ -82,17 +88,15 @@ def _comm_matrix_pallas(trace, *, output: str = "size") -> np.ndarray:
     """Accelerator comm matrix: canonical-ordered send records through the
     pair_sum one-hot-matmul kernel (f32 rounding; counts exact)."""
     s = _sends(trace)
-    n = trace.num_processes
-    if len(s) == 0 or n == 0:
-        return np.zeros((n, n))
-    src = np.asarray(s[PROC], np.int64)
-    dst = np.asarray(s[PARTNER], np.int64)
-    w = np.nan_to_num(np.asarray(s[MSG_SIZE], np.float64)) \
-        if output == "size" else np.ones(len(s))
-    dst = _wrap_partners(src, dst, n, "comm_matrix backend='pallas'")
-    ts = np.asarray(s[TS], np.float64)
-    o = accel.canonical_order(ts, ts, src, dst, w)
-    return accel.pair_sum(src[o], dst[o], w[o], n, n)
+    recs = accel.Records(names=False)
+    if len(s):
+        ts = np.asarray(s[TS], np.float64)
+        recs.add(ts, ts, np.asarray(s[PROC], np.int64),
+                 np.asarray(s[PARTNER], np.int64),
+                 np.nan_to_num(np.asarray(s[MSG_SIZE], np.float64))
+                 if output == "size" else np.ones(len(s)))
+    return _comm_from_records(recs, trace.num_processes,
+                              "comm_matrix backend='pallas'")
 
 
 @register_op("message_histogram")
@@ -239,21 +243,15 @@ def _check_partner_range(extent: int, n: int, op: str) -> None:
 @register_streaming("comm_matrix")
 class _CommMatrixAgg(StreamAgg):
     """Combinable comm matrix: per-chunk (sender, receiver) partial sums.
-    ``backend="pallas"`` buffers the send records and runs the pair_sum
-    kernel once at finalize, exactly like the eager pallas backend."""
+    ``backend="pallas"`` buffers the send records and finalizes through
+    :func:`_comm_from_records`, like the eager pallas backend."""
 
     supports_parallel = True
 
     def __init__(self, output: str = "size", backend: str = "numpy"):
-        get_backend("comm_matrix", backend)
-        if backend not in ("numpy", "pallas"):
-            raise StreamingUnsupported(
-                f"streaming comm_matrix supports backends ('numpy', "
-                f"'pallas'); {backend!r} is trace-level — materialize with "
-                f".collect() to use it")
-        self.backend = backend
+        self.backend = stream_backend("comm_matrix", backend)
         self.output = output
-        self._recs: list = []
+        self._recs = accel.Records(names=False)
         self._mat = np.zeros((0, 0))
         self._neg = np.zeros(0)  # sends with partner -1, keyed by sender
         self._extent = 0
@@ -268,8 +266,7 @@ class _CommMatrixAgg(StreamAgg):
             pos = dst >= 0
             self._extent = max(self._extent, int(src.max()) + 1,
                                int(dst[pos].max()) + 1 if pos.any() else 0)
-            self._recs.append((src, dst, w, ts))
-            return
+            return self._recs.add(ts, ts, src, dst, w)
         neg = dst < 0
         if np.any(neg):
             # the in-memory op's np.add.at wraps dst=-1 into the LAST
@@ -290,8 +287,7 @@ class _CommMatrixAgg(StreamAgg):
         # everything is keyed by global process ids — no name remap at all
         self._extent = max(self._extent, other._extent)
         if self.backend != "numpy":
-            self._recs.extend(other._recs)
-            return
+            return self._recs.merge(other._recs, code_map)
         self._mat = grow_to(self._mat, other._mat.shape)
         a, b = other._mat.shape
         self._mat[:a, :b] += other._mat
@@ -302,15 +298,7 @@ class _CommMatrixAgg(StreamAgg):
         n = ctx.num_processes
         _check_partner_range(self._extent, n, "comm_matrix")
         if self.backend != "numpy":
-            if not self._recs or n == 0:
-                return np.zeros((n, n))
-            src = np.concatenate([r[0] for r in self._recs])
-            dst = np.concatenate([r[1] for r in self._recs])
-            w = np.concatenate([r[2] for r in self._recs])
-            ts = np.concatenate([r[3] for r in self._recs])
-            dst = _wrap_partners(src, dst, n, "streaming comm_matrix")
-            o = accel.canonical_order(ts, ts, src, dst, w)
-            return accel.pair_sum(src[o], dst[o], w[o], n, n)
+            return _comm_from_records(self._recs, n, "streaming comm_matrix")
         out = np.zeros((max(n, 0), max(n, 0)))
         sub = self._mat[:n, :n]
         out[: sub.shape[0], : sub.shape[1]] = sub
@@ -384,15 +372,9 @@ class _MessageHistogramAgg(StreamAgg):
     supports_parallel = True
 
     def __init__(self, bins: int = 10, backend: str = "numpy"):
-        get_backend("message_histogram", backend)
-        if backend not in ("numpy", "pallas"):
-            raise StreamingUnsupported(
-                f"streaming message_histogram supports backends ('numpy', "
-                f"'pallas'); {backend!r} is trace-level — materialize with "
-                f".collect() to use it")
-        self.backend = backend
+        self.backend = stream_backend("message_histogram", backend)
         self.bins = bins
-        self._sizes: list = []
+        self._recs = accel.Records(names=False)
         self._counts = np.zeros(bins, np.int64)
         self._edges: Optional[np.ndarray] = None
 
@@ -409,18 +391,16 @@ class _MessageHistogramAgg(StreamAgg):
         s = _chunk_sends(chunk)
         if s is None:
             return
-        _src, _dst, size, _ts = s
+        src, dst, size, ts = s
         if self.backend != "numpy":
-            self._sizes.append(size)
-            return
+            return self._recs.add(ts, ts, src, dst, size)
         c, _ = np.histogram(size, bins=self._edges)
         self._counts += c
 
     def merge_from(self, other, code_map) -> None:
         # edges were fixed by the shared stats pre-pass; counts just add
         if self.backend != "numpy":
-            self._sizes.extend(other._sizes)
-            return
+            return self._recs.merge(other._recs, code_map)
         self._counts += other._counts
 
     def result(self, ctx) -> Tuple[np.ndarray, np.ndarray]:
@@ -428,8 +408,7 @@ class _MessageHistogramAgg(StreamAgg):
             return np.zeros(self.bins, np.int64), np.linspace(0, 1,
                                                               self.bins + 1)
         if self.backend != "numpy":
-            sizes = (np.concatenate(self._sizes) if self._sizes
-                     else np.zeros(0))
+            *_, sizes = self._recs.columns()
             return accel.hist_counts(
                 _hist_indices(sizes, self._edges, self.bins),
                 self.bins), self._edges

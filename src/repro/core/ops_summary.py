@@ -12,39 +12,18 @@ for integer-ns traces — see docs/streaming.md).
 
 from __future__ import annotations
 
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import Optional, Sequence
 
 import numpy as np
 
 from . import accel
 from .constants import (DEFAULT_IDLE_NAMES, ENTER, ET, EXC, INC, NAME, PROC, TS)
 from .frame import Categorical, EventFrame
-from .registry import (get_backend, op_backends, register_backend,
-                       register_op, register_streaming)
-from .streaming import StreamAgg, StreamingUnsupported, grow_to
+from .registry import (get_backend, register_backend, register_op,
+                       register_streaming)
+from .streaming import (StreamAgg, StreamingUnsupported, grow_to,
+                        stream_backend)
 from ..runtime import tracer
-
-
-# ---------------------------------------------------------------------------
-# time_profile backends (the prototype of the per-op backend registry)
-# ---------------------------------------------------------------------------
-
-#: the live ``time_profile`` backend table — an alias of
-#: ``registry.op_backends("time_profile")`` kept for backwards
-#: compatibility (mutating it *is* registration).  A backend maps call
-#: records onto the [bins, functions] overlap matrix:
-#: ``fn(starts, ends, rate, name_codes, edges, nf) -> np.ndarray``
-#: with ``starts``/``ends`` float64 ns, ``rate`` weight/ns, ``name_codes``
-#: int codes < nf, ``edges`` the bin edge array (len num_bins+1).
-TIME_PROFILE_BACKENDS: Dict[str, Callable[..., np.ndarray]] = \
-    op_backends("time_profile")
-
-
-def register_time_profile_backend(name: str) -> Callable:
-    """Decorator registering a ``time_profile(backend=<name>)`` accumulation
-    backend (last registration wins, like the op registry).  Equivalent to
-    ``registry.register_backend("time_profile", name)``."""
-    return register_backend("time_profile", name)
 
 
 @register_op("flat_profile", needs_structure=True)
@@ -96,27 +75,41 @@ def _flat_profile_numpy(trace, *, metrics: Sequence[str] = (EXC,),
 
 def _flat_assemble(names_alpha, counts, sums, metrics, per_process
                    ) -> EventFrame:
-    """Shared finalization of the record-level flat_profile paths: counts
-    (exact int64) and per-metric sums, both on the alphabetical name axis,
-    become the output frame.  Used by the streaming aggregator and the
-    pallas backend on every path — identical assembly is half of the
-    digest-identity contract."""
+    """Shared finalization of the flat_profile paths: counts (exact int64)
+    and per-metric sums, both on the alphabetical name axis, become the
+    output frame."""
     out = EventFrame()
+    at = np.nonzero(counts)   # (names,) or (names, processes)
+    out[NAME] = Categorical(at[0].astype(np.int32), names_alpha)
     if per_process:
-        f_alpha, p_alpha = np.nonzero(counts)
-        out[NAME] = Categorical(f_alpha.astype(np.int32), names_alpha)
-        out[PROC] = p_alpha.astype(np.int64)
-        out["count"] = counts[f_alpha, p_alpha]
-        for i, m in enumerate(metrics):
-            out[m] = sums[i, f_alpha, p_alpha]
-    else:
-        present = np.nonzero(counts)[0]
-        out[NAME] = Categorical(present.astype(np.int32), names_alpha)
-        out["count"] = counts[present]
-        for i, m in enumerate(metrics):
-            out[m] = sums[i, present]
+        out[PROC] = at[1].astype(np.int64)
+    out["count"] = counts[at]
+    for i, m in enumerate(metrics):
+        out[m] = sums[i][at]
     order = np.argsort(-np.asarray(out[metrics[0]]), kind="stable")
     return out.take(order)
+
+
+def _flat_from_records(recs, names, counts, poison, metrics, per_process
+                       ) -> EventFrame:
+    """The pallas flat_profile of every path: the call records' metric
+    sums through seg_sum (or pair_sum per process), in canonical order.
+    ``counts`` are the exact call counts and ``poison[i]`` indexes the
+    groups whose metric ``i`` an unmatched enter collapses to 0, both in
+    the name codes of ``names``."""
+    names_alpha, order, inv = accel.alpha_positions(names)
+    nf = len(names_alpha)
+    proc, code, vals = recs.ordered("proc", "code", "value", inv=inv)
+    if per_process:
+        sums = np.stack([accel.pair_sum(code, proc, vals[:, i], nf,
+                                        counts.shape[1])
+                         for i in range(len(metrics))])
+    else:
+        sums = accel.seg_sum(code, vals, nf).T
+    for i, (names_at, *procs_at) in enumerate(poison):
+        sums[i][(inv[names_at], *procs_at)] = 0.0
+    return _flat_assemble(names_alpha, counts[order], sums, metrics,
+                          per_process)
 
 
 @register_backend("flat_profile", "pallas")
@@ -132,48 +125,22 @@ def _flat_profile_pallas(trace, *, metrics: Sequence[str] = (EXC,),
             f"groupby_column={groupby_column!r}; use backend='numpy'")
     metrics = list(metrics)
     ev = trace.events
-    is_enter = ev.cat(ET).mask_eq(ENTER)
-    match = np.asarray(ev.column("_matching_event"), np.int64)
-    ts = np.asarray(ev[TS], np.float64)
-    codes = ev.codes(NAME)
-    procs = np.asarray(ev[PROC], np.int64)
-    names_alpha, _order, inv = accel.alpha_positions(ev.cat(NAME).categories)
-    nf = len(names_alpha)
-    nprocs = max(trace.num_processes, 1)
-
-    ent = np.nonzero(is_enter)[0]
-    acode_all = inv[codes[ent]]
+    ent = np.nonzero(ev.cat(ET).mask_eq(ENTER))[0]
+    names = ev.cat(NAME).categories
+    # every Enter counts; its group's sum is NaN-poisoned when its metric
+    # is NaN (an unmatched enter), mirroring nan_to_num-after-groupby
+    at = (ev.codes(NAME)[ent],)
     if per_process:
-        counts = np.zeros((nf, nprocs), np.int64)
-        np.add.at(counts, (acode_all, procs[ent]), 1)
+        at += (np.asarray(ev[PROC], np.int64)[ent],)
+        counts = np.zeros((len(names), max(trace.num_processes, 1)),
+                          np.int64)
+        np.add.at(counts, at, 1)
     else:
-        counts = np.bincount(acode_all, minlength=nf).astype(np.int64)
-
-    # kernel records: matched calls only (unmatched enters contribute
-    # exactly 0 to the numpy sums; the NaN-poisoning they cause is applied
-    # per metric below, mirroring nan_to_num-after-groupby)
-    msel = np.nonzero(is_enter & (match >= 0))[0]
-    vals = np.stack([np.nan_to_num(
-        np.asarray(ev.column(m), np.float64)[msel]) for m in metrics],
-        axis=1)
-    acode = inv[codes[msel]]
-    pr = procs[msel]
-    o = accel.canonical_order(ts[msel], ts[match[msel]], pr, acode,
-                              vals[:, 0])
-    if per_process:
-        sums = np.stack([accel.pair_sum(acode[o], pr[o], vals[o, i],
-                                        nf, nprocs)
-                         for i in range(len(metrics))])
-    else:
-        sums = accel.seg_sum(acode[o], vals[o], nf).T
-    for i, m in enumerate(metrics):
-        bad = np.isnan(np.asarray(ev.column(m), np.float64)[ent])
-        if bad.any():
-            if per_process:
-                sums[i][acode_all[bad], procs[ent][bad]] = 0.0
-            else:
-                sums[i][acode_all[bad]] = 0.0
-    return _flat_assemble(names_alpha, counts, sums, metrics, per_process)
+        counts = np.bincount(at[0], minlength=len(names)).astype(np.int64)
+    poison = [tuple(a[np.isnan(np.asarray(ev.column(m), np.float64)[ent])]
+                    for a in at) for m in metrics]
+    return _flat_from_records(accel.matched_calls(ev, metrics), names,
+                              counts, poison, metrics, per_process)
 
 
 @register_op("time_profile", needs_structure=True)
@@ -192,14 +159,15 @@ def time_profile(trace, num_bins: int = 32, metric: str = EXC,
         metric: ``time.exc`` (default) or ``time.inc``, in ns.
         normalized: scale each bin's values to fractions of that bin's
             total (rows sum to 1 where any time was recorded).
-        backend: a backend registered in :data:`TIME_PROFILE_BACKENDS`
-            (the live ``registry.op_backends("time_profile")`` table) —
-            built-ins are ``"numpy"`` (exact sweep) and ``"pallas"``
-            (tiled kernel); register your own with
-            :func:`register_time_profile_backend`.  Non-numpy backends
-            run on canonically ordered call records, so every execution
-            path (eager, streaming, parallel, pack) produces an
-            identical frame.
+        backend: a registered ``time_profile`` backend — built-ins are
+            ``"numpy"`` (exact sweep) and ``"pallas"`` (tiled kernel);
+            register your own with
+            ``registry.register_backend("time_profile", name)``.  A
+            backend maps call records onto the [bins, functions] overlap
+            matrix, ``fn(starts, ends, rate, name_codes, edges, nf)``.
+            Non-numpy backends run on canonically ordered call records,
+            so every execution path (eager, streaming, parallel, pack)
+            produces an identical frame.
 
     Returns:
         EventFrame with ``bin_start``/``bin_end`` (ns) plus one column per
@@ -214,6 +182,12 @@ def time_profile(trace, num_bins: int = 32, metric: str = EXC,
     if t1 <= t0:
         t1 = t0 + 1.0
     edges = np.linspace(t0, t1, num_bins + 1)
+    cats = ev.cat(NAME).categories
+
+    fn = get_backend("time_profile", backend)
+    if backend != "numpy":
+        return _profile_from_records(accel.matched_calls(ev, metric), cats,
+                                     edges, num_bins, normalized, fn)
 
     is_enter = ev.cat(ET).mask_eq(ENTER)
     match = np.asarray(ev.column("_matching_event"), np.int64)
@@ -222,19 +196,7 @@ def time_profile(trace, num_bins: int = 32, metric: str = EXC,
     ends = ts[match[sel]]
     w = np.nan_to_num(np.asarray(ev.column(metric), np.float64)[sel])
     name_codes = ev.codes(NAME)[sel]
-    cats = ev.cat(NAME).categories
     nf = len(cats)
-
-    fn = get_backend("time_profile", backend)
-    if backend != "numpy":
-        # record-level path shared with the streaming finalizer: canonical
-        # order + alphabetical code space ⇒ identical frames on every path
-        names_alpha, _order, inv = accel.alpha_positions(cats)
-        procs = np.asarray(ev[PROC], np.int64)[sel]
-        return _profile_from_records(starts, ends, w, procs,
-                                     inv[name_codes], names_alpha, edges,
-                                     num_bins, normalized, fn)
-
     inc = ends - starts
     rate = np.where(inc > 0, w / np.maximum(inc, 1e-30), 0.0)
     prof = fn(starts, ends, rate, name_codes, edges, nf)
@@ -256,15 +218,16 @@ def time_profile(trace, num_bins: int = 32, metric: str = EXC,
     return out
 
 
-def _profile_from_records(starts, ends, w, procs, acodes, names_alpha,
-                          edges, num_bins, normalized, fn) -> EventFrame:
+def _profile_from_records(recs, names, edges, num_bins, normalized, fn
+                          ) -> EventFrame:
     """Record-level ``time_profile`` core for non-numpy backends, shared by
     the eager op and the streaming finalizer: canonical-sort the call
     records, invoke the backend once, apply the zero-duration fixup and
     assemble columns in the alphabetical code space.  Both paths hold the
     same record multiset, so the resulting frames are identical."""
-    o = accel.canonical_order(starts, ends, procs, acodes, w)
-    starts, ends, w, acodes = starts[o], ends[o], w[o], acodes[o]
+    names_alpha, _order, inv = accel.alpha_positions(names)
+    starts, ends, acodes, w = recs.ordered("start", "end", "code", "value",
+                                           inv=inv)
     inc = ends - starts
     rate = np.where(inc > 0, w / np.maximum(inc, 1e-30), 0.0)
     prof = np.asarray(fn(starts, ends, rate, acodes, edges,
@@ -285,7 +248,7 @@ def _profile_from_records(starts, ends, w, procs, acodes, names_alpha,
     return out
 
 
-@register_time_profile_backend("pallas")
+@register_backend("time_profile", "pallas")
 def _pallas_profile(starts, ends, rate, name_codes, edges, nf) -> np.ndarray:
     """The Pallas TPU kernel (repro.kernels.time_bin): scatter-free one-hot
     matmul accumulation, interpret-mode on CPU.  Values agree with the
@@ -310,7 +273,7 @@ def _pallas_profile(starts, ends, rate, name_codes, edges, nf) -> np.ndarray:
     return out
 
 
-@register_time_profile_backend("numpy")
+@register_backend("time_profile", "numpy")
 def _exact_profile(starts, ends, rate, name_codes, edges, nf) -> np.ndarray:
     """C(t) = Σ rate_i·clamp(t−s_i, 0, e_i−s_i) evaluated at edges, per name.
 
@@ -409,6 +372,17 @@ def _load_imbalance_numpy(trace, *, metric: str = EXC,
                                top_functions, nprocs)
 
 
+def _imbalance_from_records(recs, names, nprocs, metric, num_processes,
+                           top_functions) -> EventFrame:
+    """The pallas load_imbalance of every path: the call records'
+    function × rank totals through pair_sum, in canonical order."""
+    names_alpha, _order, inv = accel.alpha_positions(names)
+    proc, code, vals = recs.ordered("proc", "code", "value", inv=inv)
+    tot = accel.pair_sum(code, proc, vals, len(names_alpha), max(nprocs, 1))
+    return _imbalance_assemble(tot, names_alpha, metric, num_processes,
+                               top_functions, nprocs)
+
+
 @register_backend("load_imbalance", "pallas")
 def _load_imbalance_pallas(trace, *, metric: str = EXC,
                            num_processes: int = 5,
@@ -419,20 +393,9 @@ def _load_imbalance_pallas(trace, *, metric: str = EXC,
     f32 rounding; unmatched enters contribute exactly 0 in the reference
     and are simply dropped here)."""
     ev = trace.events
-    ts = np.asarray(ev[TS], np.float64)
-    is_enter = ev.cat(ET).mask_eq(ENTER)
-    match = np.asarray(ev.column("_matching_event"), np.int64)
-    sel = np.nonzero(is_enter & (match >= 0))[0]
-    vals = np.nan_to_num(np.asarray(ev.column(metric), np.float64)[sel])
-    names_alpha, _order, inv = accel.alpha_positions(ev.cat(NAME).categories)
-    acode = inv[ev.codes(NAME)[sel]]
-    procs = np.asarray(ev[PROC], np.int64)[sel]
-    nprocs = trace.num_processes
-    o = accel.canonical_order(ts[sel], ts[match[sel]], procs, acode, vals)
-    tot = accel.pair_sum(acode[o], procs[o], vals[o], len(names_alpha),
-                         max(nprocs, 1))
-    return _imbalance_assemble(tot, names_alpha, metric, num_processes,
-                               top_functions, nprocs)
+    return _imbalance_from_records(
+        accel.matched_calls(ev, metric), ev.cat(NAME).categories,
+        trace.num_processes, metric, num_processes, top_functions)
 
 
 @register_op("idle_time", needs_structure=True)
@@ -476,18 +439,6 @@ def _check_metric(metric: str, op: str) -> None:
         raise StreamingUnsupported(
             f"streaming {op} supports metrics {_CALL_METRICS}, got "
             f"{metric!r}; materialize with .collect() for custom metrics")
-
-
-def _alpha(ctx, nf: int):
-    """(sorted names, gather order, code→alphabetical-position map) over the
-    first ``nf`` global codes — restores the category-code group order the
-    in-memory groupby produces.  ``arr[order]`` re-orders a code-indexed
-    axis alphabetically; ``inv[code]`` is a code's alphabetical position."""
-    names = np.asarray(ctx.names.names[:nf], dtype=object).astype(str)
-    order = np.argsort(names, kind="stable")
-    inv = np.empty(nf, np.int64)
-    inv[order] = np.arange(nf)
-    return names[order], order, inv
 
 
 def _pad_to(arr: np.ndarray, shape) -> np.ndarray:
@@ -538,9 +489,8 @@ class _FlatProfileAgg(StreamAgg):
     its group total collapses to 0 (``nan_to_num`` after aggregation).
 
     ``backend="pallas"`` buffers the completed-call records instead of
-    accumulating sums, then canonical-sorts and invokes the kernel once at
-    finalize — exactly what the eager pallas backend does, so the two paths
-    produce byte-identical frames (counts stay exact either way)."""
+    accumulating sums and finalizes through :func:`_flat_from_records`,
+    like the eager pallas backend (counts stay exact either way)."""
 
     needs_calls = True
     supports_parallel = True
@@ -552,19 +502,13 @@ class _FlatProfileAgg(StreamAgg):
             raise StreamingUnsupported(
                 f"streaming flat_profile groups by {NAME!r} only, got "
                 f"groupby_column={groupby_column!r}")
-        get_backend("flat_profile", backend)  # fail fast on unknown names
-        if backend not in ("numpy", "pallas"):
-            raise StreamingUnsupported(
-                f"streaming flat_profile supports backends ('numpy', "
-                f"'pallas'); {backend!r} is trace-level — materialize with "
-                f".collect() to use it")
-        self.backend = backend
+        self.backend = stream_backend("flat_profile", backend)
         self.metrics = list(metrics)
         for m in self.metrics:
             _check_metric(m, "flat_profile")
         self.per_process = per_process
         nm = len(self.metrics)
-        self._recs: List[tuple] = []
+        self._recs = accel.Records(width=nm)
         if per_process:
             self._counts = np.zeros((0, 0), np.int64)
             self._sums = np.zeros((nm, 0, 0))
@@ -580,11 +524,10 @@ class _FlatProfileAgg(StreamAgg):
         nf = len(chunk.names)
         metric_vals = {INC: calls.inc, EXC: calls.exc}
         if self.backend != "numpy":
-            vals = np.stack([np.nan_to_num(metric_vals[m])
-                             for m in self.metrics], axis=1) \
-                if len(calls.name) else np.zeros((0, len(self.metrics)))
-            self._recs.append((calls.name.copy(), calls.proc.copy(),
-                               calls.start.copy(), calls.end.copy(), vals))
+            self._recs.add(calls.start.copy(), calls.end.copy(),
+                           calls.proc.copy(), calls.name.copy(),
+                           np.stack([np.nan_to_num(metric_vals[m])
+                                     for m in self.metrics], axis=1))
         if self.per_process:
             procs = np.asarray(ev[PROC], np.int64)[is_enter]
             np_ = int(max(procs.max() + 1 if len(procs) else 0,
@@ -614,23 +557,7 @@ class _FlatProfileAgg(StreamAgg):
             self._sums = _scatter_names(self._sums, other._sums, code_map,
                                         axis=1)
         else:
-            for name, proc, start, end, vals in other._recs:
-                self._recs.append((code_map[name], proc, start, end, vals))
-
-    def _gather_records(self, inv):
-        """Concatenate the buffered call records into flat arrays with
-        alphabetical name positions — shared by the pallas finalizers."""
-        if self._recs:
-            name = np.concatenate([r[0] for r in self._recs])
-            proc = np.concatenate([r[1] for r in self._recs])
-            start = np.concatenate([r[2] for r in self._recs])
-            end = np.concatenate([r[3] for r in self._recs])
-            vals = np.concatenate([r[4] for r in self._recs])
-        else:
-            name = proc = np.zeros(0, np.int64)
-            start = end = np.zeros(0)
-            vals = np.zeros((0, len(self.metrics)))
-        return inv[name], proc, start, end, vals
+            self._recs.merge(other._recs, code_map)
 
     def result(self, ctx) -> EventFrame:
         nf = len(ctx.names)
@@ -640,37 +567,25 @@ class _FlatProfileAgg(StreamAgg):
             for m in self.metrics:
                 out[m] = np.asarray([])
             return out
-        names_alpha, order, inv = _alpha(ctx, nf)
-        open_names, open_procs = ctx.open_calls
+        names = ctx.names.names[:nf]
+        open_at = ctx.open_calls
         nm = len(self.metrics)
         if self.per_process:
             np_ = max(self._counts.shape[1], self._sums.shape[2],
                       ctx.num_processes, 1)
-            counts = _pad_to(self._counts, (nf, np_))[order]
-            if self.backend == "numpy":
-                sums = _pad_to(self._sums, (nm, nf, np_))[:, order]
-            else:
-                acode, proc, start, end, vals = self._gather_records(inv)
-                o = accel.canonical_order(start, end, proc, acode,
-                                          vals[:, 0] if nm else start)
-                sums = np.stack([accel.pair_sum(acode[o], proc[o],
-                                                vals[o, i], nf, np_)
-                                 for i in range(nm)]) \
-                    if nm else np.zeros((0, nf, np_))
-            if len(open_names):
-                sums[:, inv[open_names], open_procs] = 0.0
+            counts = _pad_to(self._counts, (nf, np_))
         else:
-            counts = _pad_to(self._counts, (nf,))[order]
-            if self.backend == "numpy":
-                sums = _pad_to(self._sums, (nm, nf))[:, order]
-            else:
-                acode, proc, start, end, vals = self._gather_records(inv)
-                o = accel.canonical_order(start, end, proc, acode,
-                                          vals[:, 0] if nm else start)
-                sums = accel.seg_sum(acode[o], vals[o], nf).T
-            if len(open_names):
-                sums[:, inv[open_names]] = 0.0
-        return _flat_assemble(names_alpha, counts, sums, self.metrics,
+            counts = _pad_to(self._counts, (nf,))
+            open_at = open_at[:1]
+        if self.backend != "numpy":
+            return _flat_from_records(self._recs, names, counts,
+                                      [open_at] * nm, self.metrics,
+                                      self.per_process)
+        names_alpha, order, inv = accel.alpha_positions(names)
+        sums = _pad_to(self._sums, (nm,) + counts.shape)[:, order]
+        if len(open_at[0]):
+            sums[(slice(None), inv[open_at[0]], *open_at[1:])] = 0.0
+        return _flat_assemble(names_alpha, counts[order], sums, self.metrics,
                               self.per_process)
 
 
@@ -685,8 +600,8 @@ class _TimeProfileAgg(StreamAgg):
 
     Non-numpy backends (record-level contract) buffer the completed-call
     records and run :func:`_profile_from_records` at finalize — the same
-    canonical-sort + single-kernel-call core the eager op uses, so e.g.
-    ``backend="pallas"`` yields byte-identical frames on both paths."""
+    core the eager op uses, so e.g. ``backend="pallas"`` yields
+    byte-identical frames on both paths."""
 
     needs_calls = True
     needs_stats = True
@@ -700,7 +615,7 @@ class _TimeProfileAgg(StreamAgg):
         self.num_bins = num_bins
         self.metric = metric
         self.normalized = normalized
-        self._recs: List[tuple] = []
+        self._recs = accel.Records()
         self._H = np.zeros((5, num_bins + 2, 0))
         self._Z = np.zeros((num_bins, 0))
         self._edges: Optional[np.ndarray] = None
@@ -718,10 +633,10 @@ class _TimeProfileAgg(StreamAgg):
         if calls is None or len(calls.name) == 0:
             return
         if self.backend != "numpy":
-            w = np.nan_to_num(calls.inc if self.metric == INC else calls.exc)
-            self._recs.append((calls.name.copy(), calls.proc.copy(),
-                               calls.start.copy(), calls.end.copy(), w))
-            return
+            return self._recs.add(calls.start.copy(), calls.end.copy(),
+                                  calls.proc.copy(), calls.name.copy(),
+                                  np.nan_to_num(calls.inc if self.metric == INC
+                                                else calls.exc))
         nf = len(chunk.names)
         self._H = grow_to(self._H, (5, self.num_bins + 2, nf))
         self._Z = grow_to(self._Z, (self.num_bins, nf))
@@ -748,9 +663,7 @@ class _TimeProfileAgg(StreamAgg):
         # bin edges come from the shared stats pre-pass, so workers and
         # parent agree on them; only the name axis needs remapping
         if self.backend != "numpy":
-            for name, proc, start, end, w in other._recs:
-                self._recs.append((code_map[name], proc, start, end, w))
-            return
+            return self._recs.merge(other._recs, code_map)
         self._H = _scatter_names(self._H, other._H, code_map, axis=2)
         self._Z = _scatter_names(self._Z, other._Z, code_map, axis=1)
 
@@ -760,27 +673,16 @@ class _TimeProfileAgg(StreamAgg):
                                "bin_end": np.asarray([])})
         nf = len(ctx.names)
         if self.backend != "numpy":
-            names_alpha, _order, inv = _alpha(ctx, nf)
-            if self._recs:
-                name = np.concatenate([r[0] for r in self._recs])
-                proc = np.concatenate([r[1] for r in self._recs])
-                start = np.concatenate([r[2] for r in self._recs])
-                end = np.concatenate([r[3] for r in self._recs])
-                w = np.concatenate([r[4] for r in self._recs])
-            else:
-                name = proc = np.zeros(0, np.int64)
-                start = end = w = np.zeros(0)
-            return _profile_from_records(start, end, w, proc, inv[name],
-                                         names_alpha, self._edges,
-                                         self.num_bins, self.normalized,
-                                         self._fn)
+            return _profile_from_records(self._recs, ctx.names.names[:nf],
+                                         self._edges, self.num_bins,
+                                         self.normalized, self._fn)
         H = _pad_to(self._H, (5, self.num_bins + 2, nf))
         Z = _pad_to(self._Z, (self.num_bins, nf))
         cum = np.cumsum(H[:, : self.num_bins + 1, :], axis=1)
         t = self._edges[:, None]
         C = t * (cum[0] - cum[1]) - (cum[2] - cum[3]) + cum[4]
         prof = np.maximum(np.diff(C, axis=0), 0.0) + Z
-        names_alpha, order, _inv = _alpha(ctx, nf)
+        names_alpha, order, _inv = accel.alpha_positions(ctx.names.names[:nf])
         prof = prof[:, order]
         if self.normalized:
             denom = prof.sum(axis=1, keepdims=True)
@@ -799,7 +701,7 @@ class _LoadImbalanceAgg(StreamAgg):
     """Combinable load imbalance: the per-(function, process) metric totals
     merge exactly across chunks (integer-ns sums); the ratio arithmetic at
     finalize is identical to the in-memory op.  ``backend="pallas"``
-    buffers records and runs the pair_sum kernel once at finalize, exactly
+    buffers records and finalizes through :func:`_imbalance_from_records`,
     like the eager pallas backend."""
 
     needs_calls = True
@@ -809,17 +711,11 @@ class _LoadImbalanceAgg(StreamAgg):
                  top_functions: Optional[int] = None,
                  backend: str = "numpy"):
         _check_metric(metric, "load_imbalance")
-        get_backend("load_imbalance", backend)
-        if backend not in ("numpy", "pallas"):
-            raise StreamingUnsupported(
-                f"streaming load_imbalance supports backends ('numpy', "
-                f"'pallas'); {backend!r} is trace-level — materialize with "
-                f".collect() to use it")
-        self.backend = backend
+        self.backend = stream_backend("load_imbalance", backend)
         self.metric = metric
         self.num_processes = num_processes
         self.top_functions = top_functions
-        self._recs: List[tuple] = []
+        self._recs = accel.Records()
         self._tot = np.zeros((0, 0))
 
     def update(self, chunk) -> None:
@@ -828,10 +724,9 @@ class _LoadImbalanceAgg(StreamAgg):
             return
         vals = calls.inc if self.metric == INC else calls.exc
         if self.backend != "numpy":
-            self._recs.append((calls.name.copy(), calls.proc.copy(),
-                               calls.start.copy(), calls.end.copy(),
-                               np.nan_to_num(vals)))
-            return
+            return self._recs.add(calls.start.copy(), calls.end.copy(),
+                                  calls.proc.copy(), calls.name.copy(),
+                                  np.nan_to_num(vals))
         nf = len(chunk.names)
         np_ = int(calls.proc.max()) + 1
         self._tot = grow_to(self._tot, (nf, np_))
@@ -839,31 +734,19 @@ class _LoadImbalanceAgg(StreamAgg):
 
     def merge_from(self, other, code_map) -> None:
         if self.backend != "numpy":
-            for name, proc, start, end, vals in other._recs:
-                self._recs.append((code_map[name], proc, start, end, vals))
-            return
+            return self._recs.merge(other._recs, code_map)
         self._tot = _scatter_names(self._tot, other._tot, code_map, axis=0)
 
     def result(self, ctx) -> EventFrame:
         nf = len(ctx.names)
         nprocs = ctx.num_processes
-        names_alpha, order, inv = _alpha(ctx, nf)
-        if self.backend == "numpy":
-            tot = _pad_to(self._tot, (nf, max(nprocs, 1)))[order]
-        else:
-            if self._recs:
-                name = np.concatenate([r[0] for r in self._recs])
-                proc = np.concatenate([r[1] for r in self._recs])
-                start = np.concatenate([r[2] for r in self._recs])
-                end = np.concatenate([r[3] for r in self._recs])
-                vals = np.concatenate([r[4] for r in self._recs])
-            else:
-                name = proc = np.zeros(0, np.int64)
-                start = end = vals = np.zeros(0)
-            acode = inv[name]
-            o = accel.canonical_order(start, end, proc, acode, vals)
-            tot = accel.pair_sum(acode[o], proc[o], vals[o], nf,
-                                 max(nprocs, 1))
+        names = ctx.names.names[:nf]
+        if self.backend != "numpy":
+            return _imbalance_from_records(self._recs, names, nprocs,
+                                           self.metric, self.num_processes,
+                                           self.top_functions)
+        names_alpha, order, _inv = accel.alpha_positions(names)
+        tot = _pad_to(self._tot, (nf, max(nprocs, 1)))[order]
         return _imbalance_assemble(tot, names_alpha, self.metric,
                                    self.num_processes, self.top_functions,
                                    nprocs)

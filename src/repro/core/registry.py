@@ -200,8 +200,7 @@ def op_backends(op_name: str) -> Dict[str, Callable[..., Any]]:
 
     Mutating the returned dict *is* the registration mechanism —
     :func:`register_backend` writes into it, and deleting a key
-    unregisters the backend.  ``ops_summary.TIME_PROFILE_BACKENDS`` is an
-    alias of ``op_backends("time_profile")`` for backwards compatibility.
+    unregisters the backend.
     """
     return _BACKENDS.setdefault(op_name, {})
 

@@ -51,7 +51,7 @@ __all__ = ["StreamingTrace", "LiveTrace", "Watermark", "LiveResult",
            "StreamingUnsupported", "StreamAgg",
            "CallBlock", "Chunk", "StreamStats", "StreamContext",
            "execute_streaming", "iter_chunks_fallback", "grow_to",
-           "fold_frames", "mask_frames", "stats_from_frames"]
+           "stream_backend", "fold_frames", "mask_frames", "stats_from_frames"]
 
 DEFAULT_CHUNK_ROWS = 1_000_000
 
@@ -60,6 +60,19 @@ class StreamingUnsupported(RuntimeError):
     """A plan or op has no out-of-core form.  The message always names the
     escape hatches: ``.collect()`` (materialize, then run eagerly) or
     ``Trace.open(..., streaming=False)``."""
+
+
+def stream_backend(op: str, backend: str) -> str:
+    """``backend`` for ``op``'s streaming aggregate: ``numpy`` and
+    ``pallas`` stream; any other registered backend is trace-level and is
+    refused (an unknown name fails as it does eagerly)."""
+    registry.get_backend(op, backend)
+    if backend not in ("numpy", "pallas"):
+        raise StreamingUnsupported(
+            f"streaming {op} supports backends ('numpy', 'pallas'); "
+            f"{backend!r} is trace-level — materialize with .collect() to "
+            f"use it")
+    return backend
 
 
 # ---------------------------------------------------------------------------

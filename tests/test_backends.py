@@ -14,11 +14,12 @@ import numpy as np
 import pytest
 
 from repro import tracegen as tg
+import repro.core.accel as accel
 from repro.core import registry
 from repro.core.constants import EXC, INC, NAME
 from repro.core.executor import execute_parallel
 from repro.core.filters import Filter
-from repro.core.streaming import StreamingTrace
+from repro.core.streaming import StreamingTrace, StreamingUnsupported
 from repro.core.trace import Trace
 from repro.readers.jsonl import write_jsonl
 from repro.readers.pack import write_pack
@@ -36,6 +37,10 @@ OP_KWARGS = {
     "message_histogram": {"bins": 8},
     "stragglers": {"threshold": 0.05},
 }
+
+#: the ops whose pallas path sorts its records (message_histogram's
+#: counts are exact whatever the order)
+SORTING_OPS = tuple(op for op in KERNEL_OPS if op != "message_histogram")
 
 PAIRS = [(op, b) for op in KERNEL_OPS for b in registry.list_backends(op)]
 ACCEL = [(op, b) for op, b in PAIRS if b != "numpy"]
@@ -280,3 +285,56 @@ def test_streaming_time_profile_pallas_no_longer_raises(path_files):
     eager = Trace.open(pack).time_profile(num_bins=16, backend="pallas")
     stream = st.time_profile(num_bins=16, backend="pallas")
     assert result_digest(eager) == result_digest(stream)
+
+
+@pytest.mark.parametrize("path", ["eager", "streaming"])
+@pytest.mark.parametrize("op", SORTING_OPS)
+def test_one_canonical_sort_per_run(path_files, monkeypatch, op, path):
+    """Every sorting op reaches ``accel.canonical_order`` as a module
+    attribute, once per run, on every path: a wrapper set on the module
+    (as the benchmark's sort probe does) sees exactly one call."""
+    seen = []
+    orig = accel.canonical_order
+
+    def canonical_order(*args, **kwargs):
+        seen.append(len(args[0]))
+        return orig(*args, **kwargs)
+
+    monkeypatch.setattr(accel, "canonical_order", canonical_order)
+    pack, _ = path_files
+    trace = (Trace.open(pack, streaming=True, chunk_rows=97)
+             if path == "streaming" else Trace.open(pack))
+    trace.query().run(op, cache=False, backend="pallas", **OP_KWARGS[op])
+    assert len(seen) == 1, f"{op}/{path}: {len(seen)} sorts"
+    assert seen[0] > 0
+
+
+@pytest.mark.parametrize("op", KERNEL_OPS)
+def test_streaming_refuses_trace_level_backend(path_files, op):
+    """A streaming aggregate runs only the backends it can feed records:
+    a registered trace-level backend is refused with StreamingUnsupported,
+    naming the op.  time_profile's backends are record-level by contract,
+    so its aggregate takes a registered one and matches the eager run."""
+    name = "registered_test"
+    if op == "time_profile":
+        fn = registry.get_backend("time_profile", "numpy")
+    else:
+        def fn(trace, **kwargs):
+            raise AssertionError("a trace-level backend ran on a stream")
+    registry.register_backend(op, name)(fn)
+    try:
+        kw = dict(OP_KWARGS[op], backend=name)
+        if op == "time_profile":
+            pack, _ = path_files
+            eager = Trace.open(pack).time_profile(**kw)
+            stream = Trace.open(pack, streaming=True,
+                                chunk_rows=97).time_profile(**kw)
+            assert result_digest(stream) == result_digest(eager)
+        else:
+            with pytest.raises(StreamingUnsupported,
+                               match=rf"^streaming {op} supports backends "
+                                     rf"\('numpy', 'pallas'\); "
+                                     rf"'{name}' is trace-level"):
+                registry.get_op(op).streaming(**kw)
+    finally:
+        del registry.op_backends(op)[name]

@@ -82,13 +82,13 @@ def test_time_profile_backend_registry():
     """time_profile backends dispatch through the registered table: unknown
     names fail loudly listing the options, and user backends register the
     same way the built-ins do."""
-    from repro.core import ops_summary
+    from repro.core import ops_summary, registry
 
     t = tg.gol(nprocs=2, iters=2)
     with pytest.raises(ValueError, match="numpy.*pallas|pallas.*numpy"):
         t.time_profile(num_bins=8, backend="nope")
 
-    @ops_summary.register_time_profile_backend("double")
+    @registry.register_backend("time_profile", "double")
     def _double(starts, ends, rate, name_codes, edges, nf):
         return 2 * ops_summary._exact_profile(starts, ends, rate,
                                               name_codes, edges, nf)
@@ -101,7 +101,8 @@ def test_time_profile_backend_registry():
             np.testing.assert_allclose(np.asarray(b[c]),
                                        2 * np.asarray(a[c]))
     finally:
-        del ops_summary.TIME_PROFILE_BACKENDS["double"]
+        del registry.op_backends("time_profile")["double"]
+    assert "double" not in registry.op_backends("time_profile")
 
 
 def test_time_profile_pallas_backend_parity_fast():
