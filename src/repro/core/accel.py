@@ -17,15 +17,20 @@ Every path holds its records in a :class:`Records`, whose
 That order's keys are path-independent: timestamps, process ids,
 *alphabetical* name positions (never raw category or interner codes,
 which differ between the eager code space and the streaming first-seen
-code space), and the record's own value as the final tiebreak.  See docs/kernels.md for the full precision contract.
+code space), and the record's own value as the final tiebreak.
+:func:`canonical_order` finds that order in one sort on ``start``, then a
+lexsort of the tied groups only (records whose start equals another's):
+the same five-key order as a full ``np.lexsort``, so the contract does not
+change.  See docs/kernels.md for the full precision contract.
 
 This module is numpy-in / numpy-out — jax is imported lazily inside the
 kernel calls so merely importing the core never pulls the accelerator
 stack.  The sort is timed as the ``accel.sort`` span, and each adapter
 call (operand conversion, upload, dispatch, wait and fetch) as an
 ``accel.kernel`` span; the ``accel.h2d_bytes`` counter adds up the
-bytes its host operands take on the device, and ``accel.pair_tiles`` the
-output tiles each ``pair_sum`` call visited.
+bytes its host operands take on the device, ``accel.pair_tiles`` the
+output tiles each ``pair_sum`` call visited, and ``accel.sort_tied`` the
+records the sort's tie pass took.
 """
 
 from __future__ import annotations
@@ -88,13 +93,33 @@ def canonical_order(start, end, proc, code, value) -> np.ndarray:
     then ``end``, ``proc``, ``code`` (alphabetical name position — pass
     ``inv[raw_code]``), and ``value`` as the final tiebreak.  Records equal
     on *all* keys are interchangeable, so any two paths that hold the same
-    record multiset feed the kernel bit-identical blocks."""
+    record multiset feed the kernel bit-identical blocks.
+
+    The order is ``np.lexsort``'s stable five-key one, found in one sort on
+    ``start`` and a lexsort of only the records whose start ties with
+    another's (NaNs tie with each other, -0.0 with 0.0); the input index is
+    its last key.  The ``accel.sort_tied`` counter adds how many records
+    that tie pass took."""
     with tracer.span("accel.sort"):
-        return np.lexsort((np.asarray(value, np.float64),
-                           np.asarray(code, np.int64),
-                           np.asarray(proc, np.int64),
-                           np.asarray(end, np.float64),
-                           np.asarray(start, np.float64)))
+        start = np.asarray(start, np.float64)
+        o = np.argsort(start)
+        s = start[o]
+        # new[i]: s[i] begins a tie group; NaNs, sorted last, are one group
+        new = np.ones(len(s), bool)
+        np.not_equal(s[1:], s[:-1], out=new[1:])
+        new[np.searchsorted(s, np.nan) + 1:] = False
+        tied = ~new
+        tied[:-1] |= ~new[1:]
+        pos = np.flatnonzero(tied)
+        tracer.counter("accel.sort_tied", len(pos))
+        if len(pos):
+            sub = o[pos]
+            o[pos] = sub[np.lexsort((
+                sub, np.asarray(value, np.float64)[sub],
+                np.asarray(code, np.int64)[sub],
+                np.asarray(proc, np.int64)[sub],
+                np.asarray(end, np.float64)[sub], np.cumsum(new[pos])))]
+        return o
 
 
 _COLUMNS = ("start", "end", "proc", "code", "value")

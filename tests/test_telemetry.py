@@ -383,6 +383,46 @@ def test_served_scan_reuses_every_shard_open(pack_paths):
     assert _delta(s1, s2, "counters", "read.opens_fresh") == 0
 
 
+def test_served_call_scan_counts_the_tied_records(pack_paths, monkeypatch):
+    """A served job of the call ops reports ``accel.sort_tied``: the
+    records of each canonical sort whose start equals another's."""
+    from repro.core import accel
+    plancache.clear()
+    starts = []
+    real = accel.canonical_order
+
+    def canonical_order(start, *args):
+        starts.append(np.asarray(start, np.float64))
+        return real(start, *args)
+
+    def work(port):
+        with ServiceClient("127.0.0.1", port) as c:
+            q = c.open(pack_paths, streaming=True).query()
+            s0 = c.stats()["telemetry"]
+            monkeypatch.setattr(accel, "canonical_order", canonical_order)
+            try:
+                q.run("flat_profile", metrics=["time.exc", "time.inc"],
+                      backend="pallas", cache=False)
+                q.run("time_profile", num_bins=8, backend="pallas",
+                      cache=False)
+                q.run("load_imbalance", backend="pallas", cache=False)
+                q.run("stragglers", backend="pallas", cache=False)
+            finally:
+                monkeypatch.undo()
+            return s0, c.stats()["telemetry"]
+
+    s0, s1 = _serve(work)
+    assert len(starts) == 4
+    tied = 0
+    for s in starts:
+        _, inv, n = np.unique(s + 0.0, return_inverse=True,
+                              return_counts=True)
+        tied += int((n[inv] > 1).sum())
+    assert tied > 0
+    assert "accel.sort_tied" in s1["counters"]
+    assert _delta(s0, s1, "counters", "accel.sort_tied") == tied
+
+
 @pytest.mark.parametrize("first", [True, False],
                          ids=["opening", "pooled"])
 def test_served_request_takes_the_sources_identity_once(pack_paths,
